@@ -53,5 +53,7 @@ def run() -> list[tuple[str, float, str]]:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for r in run():
         print(",".join(map(str, r)))

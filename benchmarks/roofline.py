@@ -71,5 +71,7 @@ def markdown_table(cells: list[dict]) -> str:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for r in run():
         print(",".join(map(str, r)))

@@ -19,9 +19,10 @@ be handled before jax initializes, which is why this driver imports the
 benchmark modules lazily) and merges the payload under ``pe_sweep`` in
 ``BENCH_graph.json``: per-PE wall time / MTEPS, the executed exchange
 bytes and supersteps recorded by the run loop, and the interval balance.
-``--pes`` is a separate invocation from ``--json`` (enforced): forced
-host devices change XLA:CPU scheduling, so the single-PE acceptance
-sweep must never run under them.
+On an accelerator host the sweep runs on the real devices, and refuses
+to run with fewer than N.  ``--pes`` is a separate invocation from
+``--json`` (enforced): forced host devices change XLA:CPU scheduling, so
+the single-PE acceptance sweep must never run under them.
 
 ``--scale`` runs the out-of-core scale sweep (``benchmarks.scale``:
 partitioned BFS/SSSP over 500k/5M/20M-edge R-MAT containers under a
@@ -37,6 +38,8 @@ from __future__ import annotations
 import json
 import os
 import sys
+
+from repro.compile_cache import enable_compile_cache
 
 from .common import (BENCH_SCHEMA, append_history, memory_snapshot,  # noqa: F401
                      stamp as _stamp)
@@ -120,7 +123,15 @@ def _run_json(path: str) -> None:
 
 
 def _run_pes(max_pes: int, path: str) -> None:
+    import jax
+
     from . import direction
+    devices = jax.devices()
+    if len(devices) < max_pes:
+        print(f"error: --pes {max_pes} needs {max_pes} devices, found "
+              f"{len(devices)} {devices[0].platform} device(s)",
+              file=sys.stderr)
+        raise SystemExit(2)
     data = direction.collect_pe_sweep(max_pes)
     payload = {}
     if os.path.exists(path):
@@ -195,11 +206,10 @@ def main() -> None:
                   file=sys.stderr)
             raise SystemExit(2)
         del argv[i:i + 2]
-        # must land before the lazy benchmark imports pull in jax; pin
-        # the cpu platform too — forced host devices only exist on the
-        # CPU backend, so on an accelerator host the sweep would
-        # silently clamp to the single accelerator otherwise
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        # must land before the lazy benchmark imports pull in jax: on a
+        # CPU host the sweep needs N forced host devices; on an
+        # accelerator host the flag is inert and the sweep runs on the
+        # real devices (or _run_pes refuses when there are fewer than N)
         import re
         flags = os.environ.get("XLA_FLAGS", "")
         m = re.search(r"xla_force_host_platform_device_count=(\d+)", flags)
@@ -214,6 +224,7 @@ def main() -> None:
                   f"{m.group(1)} host devices (< --pes {max_pes}); "
                   "unset it or lower --pes", file=sys.stderr)
             raise SystemExit(2)
+    enable_compile_cache()    # after the device flags: this imports jax
     if "--json" in argv:
         argv.remove("--json")
         if max_pes is not None:

@@ -1,94 +1,36 @@
-"""Version shims for the small jax API surface the translator uses.
+"""The mesh/``shard_map`` defaults the translator and the LM substrate share.
 
-The translated multi-PE path uses three APIs whose spelling moved between
-jax releases; resolving them here keeps :mod:`~repro.core.scheduler` and
-:mod:`~repro.core.translator` version-agnostic:
-
-* ``make_mesh``  — ``axis_types=`` only exists on newer jax; older
-  releases get the plain call (the translator never relies on explicit
-  axis types, it only silences auto-sharding warnings where available);
-* ``shard_map``  — top-level ``jax.shard_map`` vs.
-  ``jax.experimental.shard_map.shard_map``;
-* ``pvary``      — newer jax requires marking per-PE-varying carries;
-  older versions have no such check, so the fallback is the identity.
+* ``make_mesh`` — ``jax.make_mesh`` with ``Auto`` axis types on every axis
+  (the translator never relies on explicit axis types);
+* ``shard_map_unchecked`` — ``jax.shard_map`` with the varying-manual-axes
+  (vma) checker off, for exchanges whose replicated output the checker
+  cannot type.
 """
 from __future__ import annotations
 
 import jax
 
-__all__ = ["make_mesh", "shard_map", "shard_map_unchecked", "pvary",
-           "get_abstract_mesh", "set_mesh"]
+__all__ = ["make_mesh", "shard_map_unchecked"]
 
 
 def make_mesh(axis_shapes, axis_names, devices=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with ``axis_types`` where the release supports it."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=tuple(jax.sharding.AxisType.Auto
-                             for _ in axis_names),
-            devices=devices)
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+    """``jax.make_mesh`` with ``Auto`` axis types on every axis."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=tuple(jax.sharding.AxisType.Auto for _ in axis_names),
+        devices=devices)
 
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with the static replication checker disabled.
+    """``jax.shard_map`` with the vma checker disabled (``check_vma=False``).
 
-    jax 0.4.x's ``check_rep`` pass mis-types values when a ``shard_map``
-    is batched by an outer ``vmap`` (the translated program's
-    ``run_batch``), rejecting programs that execute correctly — the
-    workaround jax itself suggests is ``check_rep=False``.  Newer
-    releases dropped the flag (the vma system replaced it), so pass it
-    only where the signature still has it.
+    The sharded exchanges end in collectives whose results are genuinely
+    replicated (every PE combines the same gathered parts), but the checker
+    cannot infer that for the quantized all-gather combine, for the
+    per-PE ``lax.switch`` before the push engine's collective, or for a
+    ``shard_map`` batched by an outer ``vmap`` (``run_batch``): it rejects
+    them with "out_specs require replication which can't be statically
+    inferred".
     """
-    import inspect
-    try:
-        has_flag = "check_rep" in inspect.signature(shard_map).parameters
-    except (TypeError, ValueError):
-        has_flag = False
-    if has_flag:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
-def pvary(x, axis_names):
-    """``jax.lax.pvary`` where it exists; identity on older releases."""
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_names)
-    return x
-
-
-def get_abstract_mesh():
-    """The ambient (context) mesh, abstractly; ``None`` when no mesh is set.
-
-    Newer jax spells this ``jax.sharding.get_abstract_mesh`` (installed by
-    ``jax.set_mesh``); on older releases the ambient mesh is the
-    ``with mesh:`` context's *physical* mesh, returned as-is — 0.4.x
-    ``shard_map`` cannot compile with an ``AbstractMesh``, and callers
-    only read ``.shape``/``.axis_names`` or pass it back to ``shard_map``.
-    """
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as mesh_lib
-    physical = mesh_lib.thread_resources.env.physical_mesh
-    if physical.empty:
-        return None
-    return physical
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` on newer releases; on older ones a ``Mesh`` is itself
-    the context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -81,14 +81,7 @@ __all__ = [
     "apply_is_elementwise",
 ]
 
-# jax.core symbols moved across jax versions; resolve once, defensively.
-try:                                      # newer jax: public extension API
-    from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var  # type: ignore
-except Exception:                         # pragma: no cover - version fallback
-    try:
-        from jax.core import ClosedJaxpr, Jaxpr, Literal, Var  # type: ignore
-    except Exception:
-        from jax._src.core import ClosedJaxpr, Jaxpr, Literal, Var  # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var
 
 # Number of abstract lanes traced; any small n > 1 works (the analysis is
 # shape-polymorphic in spirit — avals only pin dtype and rank).

@@ -154,12 +154,19 @@ class CommManager:
         }
 
     # -- placement (paper: Transport(CPU_ip, FPGA_ip, GraphCSC)) -----------
-    def transport(self, tree: Any, spec: jax.sharding.PartitionSpec | None = None) -> Any:
-        """Place a pytree on the device/mesh; records transfer bytes."""
+    def transport(self, tree: Any,
+                  spec: jax.sharding.PartitionSpec | None = None, *,
+                  mesh: jax.sharding.Mesh | None = None) -> Any:
+        """Place a pytree on the device/mesh; records transfer bytes.
+
+        ``mesh`` overrides the manager's own (the translator places a
+        plan's layouts on the plan's ``pe`` mesh).
+        """
         self.stats.record_h2d(_tree_nbytes(tree))
-        if self.mesh is None or spec is None:
+        mesh = mesh if mesh is not None else self.mesh
+        if mesh is None or spec is None:
             return jax.device_put(tree)
-        sharding = jax.sharding.NamedSharding(self.mesh, spec)
+        sharding = jax.sharding.NamedSharding(mesh, spec)
         return jax.device_put(tree, sharding)
 
     def fetch(self, tree: Any) -> Any:

@@ -30,6 +30,7 @@ import math
 
 import jax
 
+from ..errors import DeviceCountError
 from ._jax_compat import make_mesh
 
 
@@ -392,14 +393,13 @@ def plan(cfg: ScheduleConfig, *, num_vertices: int, num_edges: int,
     if cfg.pes > 1:
         devices = devices if devices is not None else jax.devices()
         if len(devices) < cfg.pes:
-            # elastic degrade: fewer PEs than asked — re-plan, don't fail
-            pes = max(1, len(devices))
-        else:
-            pes = cfg.pes
-        if pes > 1:
-            mesh = make_mesh((pes,), ("pe",), devices=devices[:pes])
-        else:
-            pes = 1
+            raise DeviceCountError(
+                f"schedule asks for pes={cfg.pes} but only {len(devices)} "
+                "device(s) are available; use plan_for_devices to re-plan "
+                "onto a smaller pool", requested=cfg.pes,
+                available=len(devices))
+        pes = cfg.pes
+        mesh = make_mesh((pes,), ("pe",), devices=devices[:pes])
     # Chunk geometry is final here — the translator must not re-derive it.
     # Round the chunk count up to a multiple of the resolved PE count so
     # each PE owns an equal-sized chunk slice, and keep chunk_size >= 1 so
@@ -414,7 +414,13 @@ def plan(cfg: ScheduleConfig, *, num_vertices: int, num_edges: int,
                         partition_budget_bytes=cfg.partition_budget_bytes)
 
 
-def plan_for_devices(cfg: ScheduleConfig, num_devices: int, **graph_meta) -> SchedulePlan:
-    """Elastic re-planning hook: same program, degraded device pool."""
-    cfg = dataclasses.replace(cfg, pes=min(cfg.pes, max(1, num_devices)))
-    return plan(cfg, **graph_meta)
+def plan_for_devices(cfg: ScheduleConfig, num_devices: int,
+                     **graph_meta) -> SchedulePlan:
+    """Elastic re-planning hook: same program, degraded device pool.
+
+    The explicit degrade path: ``pes`` is clamped to ``num_devices`` and
+    to ``jax.devices()``, where :func:`plan` itself refuses a ``pes`` the
+    pool cannot hold.
+    """
+    pes = min(cfg.pes, max(1, num_devices), len(jax.devices()))
+    return plan(dataclasses.replace(cfg, pes=pes), **graph_meta)

@@ -79,13 +79,14 @@ from ..kernels import ops as kops
 from ..kernels import pull_bitmap as pull_bitmap_kernel
 from ..kernels import push_ell as push_ell_kernel
 from ..kernels import push_scatter as push_kernel
+from ..kernels.ref import ROW_REDUCE
 from ..errors import (CheckpointCorruptError, CheckpointMismatchError,
                       DiagnosticError)
 from . import checkpoint as ckpt
 from . import faults
 from . import graph as G
 from . import preprocess
-from ._jax_compat import pvary, shard_map, shard_map_unchecked
+from ._jax_compat import shard_map_unchecked
 from .analysis import analyze_program
 from .comm import CommManager
 from .diagnostics import max_severity, render_table
@@ -208,10 +209,11 @@ class CompiledGraphProgram:
     """
 
     def __init__(self, superstep, init_state, report: TranslationReport,
-                 max_iters: int, *, push_superstep=None,
+                 max_iters: int, *, layouts: dict,
+                 push_superstep=None,
                  direction: DirectionPolicy | None = None,
-                 out_degrees=None, num_vertices: int = 0, num_edges: int = 0,
-                 rows_per_vertex=None, push_tiers: tuple | None = None,
+                 num_vertices: int = 0, num_edges: int = 0,
+                 push_tiers: tuple | None = None,
                  loop_cache: dict | None = None, push_rf_fn=None,
                  push_stat_pes: int = 1, comm: CommManager | None = None,
                  exchange_plane: str | None = None,
@@ -220,6 +222,10 @@ class CompiledGraphProgram:
                  checkpoint_dir: str | None = None,
                  checkpoint_every: int | None = None,
                  fingerprints_fn=None):
+        # every staged function takes the placed layout arrays first —
+        # ``(layouts, values, active)`` — so no jitted program closes over
+        # graph data (a closed-over array lowers to an HLO constant)
+        self._layouts = layouts
         self._superstep = superstep
         self._push_superstep = push_superstep
         self._init_state = init_state
@@ -230,16 +236,10 @@ class CompiledGraphProgram:
         # keyed per mode and identical for every translate of the same
         # (program, graph, schedule), so reuse avoids re-tracing
         self._loop_cache: dict = loop_cache if loop_cache is not None else {}
-        self._out_degrees = out_degrees
-        self._rows_per_vertex = rows_per_vertex   # (V,) fwd-ELL rows, or None
         self._push_tiers = push_tiers             # (small, large), or None
-        # live forward-ELL rows per PE: (active) -> (push_stat_pes,) int32.
-        # The sharded engine supplies its per-PE interval counter; the
-        # single-PE engine derives the scalar form from rows_per_vertex.
-        if push_rf_fn is None and rows_per_vertex is not None \
-                and push_tiers is not None:
-            def push_rf_fn(active, _rpv=rows_per_vertex):
-                return jnp.sum(jnp.where(active, _rpv, 0))[None]
+        # live forward-ELL rows per PE: (layouts, active) -> (push_stat_pes,)
+        # int32 — the sharded engine's per-PE interval counter, or the
+        # single-PE forward ELL's scalar form; None without a fwd_ell push
         self._push_rf_fn = push_rf_fn
         self._push_stat_pes = push_stat_pes       # rf vector length (static)
         self._comm = comm
@@ -272,8 +272,14 @@ class CompiledGraphProgram:
             self._fingerprints_cache = self._fingerprints_fn()
         return self._fingerprints_cache
 
+    @property
+    def layouts(self) -> dict:
+        """The placed graph layouts: the first argument of every staged
+        function (a pytree of device arrays, placed once at translate)."""
+        return self._layouts
+
     def init_state(self, roots=None, values=None):
-        return self._init_state(roots=roots, values=values)
+        return self._init_state(self._layouts, roots=roots, values=values)
 
     def superstep(self, values, active):
         """One pull-direction superstep (the canonical form).
@@ -282,7 +288,7 @@ class CompiledGraphProgram:
         (swept edges, swept/skipped blocks — see :meth:`run`); this public
         form keeps the ``(values, active)`` contract.
         """
-        new, nxt, _ = self._superstep(values, active)
+        new, nxt, _ = self._superstep(self._layouts, values, active)
         return new, nxt
 
     def superstep_push(self, values, active):
@@ -290,11 +296,13 @@ class CompiledGraphProgram:
         if self._push_superstep is None:
             raise ValueError("program was translated pull-only "
                              f"({self.report.direction_policy})")
-        return self._push_superstep(values, active)
+        return self._push_superstep(self._layouts, values, active)
 
     @property
-    def _run_loop(self):
-        """The staged while-loop for this program's own direction mode."""
+    def run_loop(self):
+        """The jitted run-to-convergence loop for this program's direction
+        mode: a pure function of ``(layouts, values, active)`` (see
+        :meth:`_staged_loop`), lowerable from shapes alone."""
         return self._staged_loop(self._mode)
 
     def _staged_loop(self, mode: str):
@@ -304,7 +312,7 @@ class CompiledGraphProgram:
         would re-trace on every :meth:`run` call — and pure (vmap-safe):
         per-lane freeze guards let :meth:`run_batch` vmap it without
         over-counting iterations on converged lanes.  The jitted function
-        maps ``(values, active)`` to ``(values, iters, (push_steps,
+        maps ``(layouts, values, active)`` to ``(values, iters, (push_steps,
         compacted_push_steps, switches, push_edges_hi, push_edges_lo,
         push_live_rows, pull_edges_hi, pull_edges_lo, pull_blocks_swept,
         pull_blocks_skipped, pull_cost))`` — both edge counters are split
@@ -332,14 +340,15 @@ class CompiledGraphProgram:
         probe = self._probe
 
         @jax.jit
-        def loop(values, active):
+        def loop(layouts, values, active):
             z = jnp.asarray(0, jnp.int32)
             state = (values, active, z, z, z, z, z, z, z,
                      jnp.zeros((n_pe,), jnp.int32), z, z, z, z,
                      jnp.asarray(E, jnp.int32))
             values, active, iters, _, pushes, compact, switches, \
                 pe_hi, pe_lo, pe_rows, pl_hi, pl_lo, bl_swept, bl_skip, \
-                pull_cost = jax.lax.while_loop(cond, body, state)
+                pull_cost = jax.lax.while_loop(
+                    cond, functools.partial(body, layouts), state)
             # exit diagnostics for run_stats['terminated']: was the
             # frontier still live (budget hit) and — probe only — did the
             # values table pick up a NaN (divergence)?
@@ -363,21 +372,21 @@ class CompiledGraphProgram:
         means a sliced run steps through the *identical* superstep
         sequence a monolithic run would, so continuation is bit-exact by
         construction.  The state tuple field order is exactly
-        :class:`BatchLaneState` (without the lane axis).
+        :class:`BatchLaneState` (without the lane axis).  ``body`` takes
+        the placed layouts first: ``body(layouts, state)``.
         """
         pull, push = self._superstep, self._push_superstep
         policy = self._direction
         V, E = self._num_vertices, self._num_edges
-        out_deg = self._out_degrees
         rf_fn = self._push_rf_fn
         n_pe = self._push_stat_pes
         tiers = self._push_tiers
         max_iters = self.max_iters
         probe = self._probe
 
-        def choose(prev_dir, active, pull_cost):
+        def choose(layouts, prev_dir, active, pull_cost):
             # frontier occupancy: n_f vertices, m_f out-edges (≤ E < 2^31)
-            m_f = jnp.sum(jnp.where(active, out_deg, 0))
+            m_f = jnp.sum(jnp.where(active, layouts["deg"], 0))
             if mode == "pull":
                 return jnp.asarray(0, jnp.int32), m_f
             if mode == "push":
@@ -393,14 +402,14 @@ class CompiledGraphProgram:
             return (jnp.where(prev_dir == 1, stay_push, enter_push)
                     .astype(jnp.int32), m_f)
 
-        def live_rows(active):
+        def live_rows(layouts, active):
             # live forward-ELL rows per PE, the quantity the push
             # superstep's tier/fallback guard switches on (recomputed
             # there: the superstep's public (values, active) signature
             # stays, and the O(R) reduce is noise next to the superstep)
             if mode == "pull" or rf_fn is None:
                 return jnp.zeros((n_pe,), jnp.int32)
-            return rf_fn(active)
+            return rf_fn(layouts, active)
 
         def compacted(direction, rf):
             # did this push superstep fit a compaction tier (vs the dense
@@ -412,31 +421,32 @@ class CompiledGraphProgram:
 
         zero_pstats = tuple(jnp.asarray(0, jnp.int32) for _ in range(3))
 
-        def step(direction, values, active):
+        def step(layouts, direction, values, active):
             # every branch returns (values, active, (swept_edges,
             # blocks_swept, blocks_skipped)) — push supersteps have no
             # pull-plane sweep, so their stats are zeros
             if mode == "pull":
-                return pull(values, active)
+                return pull(layouts, values, active)
             if mode == "push":
-                return (*push(values, active), zero_pstats)
+                return (*push(layouts, values, active), zero_pstats)
             return jax.lax.cond(
                 direction == 1,
-                lambda v, a: (*push(v, a), zero_pstats),
-                pull, values, active)
+                lambda v, a: (*push(layouts, v, a), zero_pstats),
+                lambda v, a: pull(layouts, v, a), values, active)
 
         def cond(state):
             _, active, it, *_ = state
             return jnp.logical_and(jnp.any(active), it < max_iters)
 
-        def body(state):
+        def body(layouts, state):
             values, active, it, direction, pushes, compact, switches, \
                 pe_hi, pe_lo, pe_rows, pl_hi, pl_lo, bl_swept, bl_skip, \
                 pull_cost = state
             alive = jnp.logical_and(jnp.any(active), it < max_iters)
-            new_dir, m_f = choose(direction, active, pull_cost)
-            rf = live_rows(active)
-            new_values, new_active, pstats = step(new_dir, values, active)
+            new_dir, m_f = choose(layouts, direction, active, pull_cost)
+            rf = live_rows(layouts, active)
+            new_values, new_active, pstats = step(layouts, new_dir, values,
+                                                  active)
             if probe and jnp.issubdtype(new_values.dtype, jnp.floating):
                 # divergence probe: a NaN anywhere in the new table
                 # freezes the frontier, so the loop exits on the next
@@ -569,7 +579,8 @@ class CompiledGraphProgram:
             return self._run_checkpointed(roots, values, checkpoint_dir,
                                           checkpoint_every, resume)
         values, active = self.init_state(roots=roots, values=values)
-        values, iters, stats_dev = self._run_loop(values, active)
+        values, iters, stats_dev = self.run_loop(self._layouts, values,
+                                                 active)
         # one host transfer for the whole counter tuple (a per-scalar
         # int() would pay a device sync each)
         iters, (pushes, compact, switches, pe_hi, pe_lo, pe_rows,
@@ -691,9 +702,9 @@ class CompiledGraphProgram:
         either way.
 
         Multi-PE programs batch too (the sharded push engine's
-        ``shard_map`` is staged with the replication checker disabled —
-        see :func:`repro.core._jax_compat.shard_map_unchecked` — because
-        jax 0.4.x mis-types vmapped shard_maps).  The stats' per-lane
+        ``shard_map`` is staged with the vma checker disabled — see
+        :func:`repro.core._jax_compat.shard_map_unchecked` — because the
+        checker cannot type vmapped shard_maps).  The stats' per-lane
         ``exchange_supersteps``/``exchange_bytes`` stay *logical* (the
         algorithmic cost model, matching sequential runs lane-for-lane),
         while the comm manager's executed totals record the *physical*
@@ -704,15 +715,16 @@ class CompiledGraphProgram:
         """
         roots = jnp.asarray(roots)
         loop = self._staged_loop(self._mode)
+        init_state = self._init_state
 
-        def one(root):
-            values, active = self.init_state(roots=root)
-            return loop(values, active)
+        def one(layouts, root):
+            values, active = init_state(layouts, roots=root)
+            return loop(layouts, values, active)
 
         values, iters, (pushes, compact, switches, pe_hi, pe_lo, pe_rows,
                         pl_hi, pl_lo, bl_swept, bl_skip, _, live,
                         nanfree) = \
-            jax.vmap(one)(roots)
+            jax.vmap(one, in_axes=(None, 0))(self._layouts, roots)
         iters_np = np.asarray(iters)
         stats = self._batch_stats(iters, pushes, compact, switches, pe_hi,
                                   pe_lo, pe_rows, pl_hi, pl_lo, bl_swept,
@@ -791,9 +803,9 @@ class CompiledGraphProgram:
 
     # -- lane-level continuation: resumable batched runs (serving plane) ---
 
-    def _fresh_lane(self, root) -> BatchLaneState:
+    def _fresh_lane(self, layouts, root) -> BatchLaneState:
         """One lane's freshly-rooted full loop state (no lane axis)."""
-        values, active = self._init_state(roots=root)
+        values, active = self._init_state(layouts, roots=root)
         z = jnp.asarray(0, jnp.int32)
         return BatchLaneState(
             values=values, active=active, iters=z, direction=z, pushes=z,
@@ -813,9 +825,9 @@ class CompiledGraphProgram:
         key = ("batch_init",)
         fn = self._loop_cache.get(key)
         if fn is None:
-            fn = jax.jit(jax.vmap(self._fresh_lane))
+            fn = jax.jit(jax.vmap(self._fresh_lane, in_axes=(None, 0)))
             self._loop_cache[key] = fn
-        return fn(jnp.asarray(roots))
+        return fn(self._layouts, jnp.asarray(roots))
 
     def batch_idle(self, slots: int) -> BatchLaneState:
         """An all-idle k-lane state: every lane converged, awaiting admit.
@@ -841,14 +853,14 @@ class CompiledGraphProgram:
             fresh_lane = self._fresh_lane
 
             @jax.jit
-            def admit(state, lane, root):
-                fresh = fresh_lane(root)
+            def admit(layouts, state, lane, root):
+                fresh = fresh_lane(layouts, root)
                 return jax.tree.map(
                     lambda full, one: full.at[lane].set(one), state, fresh)
 
             fn = admit
             self._loop_cache[key] = fn
-        return fn(state, jnp.asarray(lane, jnp.int32),
+        return fn(self._layouts, state, jnp.asarray(lane, jnp.int32),
                   jnp.asarray(root, jnp.int32))
 
     def run_batch_slice(self, state: BatchLaneState,
@@ -871,25 +883,31 @@ class CompiledGraphProgram:
         serving plane accounts per-harvest via :meth:`lane_stats`).
         """
         faults.trip("lane.superstep")
+        return self._slice_fn()(self._layouts, state,
+                                jnp.asarray(budget, jnp.int32))
+
+    def _slice_fn(self):
+        """The jitted, lane-vmapped budgeted slice loop behind
+        :meth:`run_batch_slice`: ``(layouts, state, budget) -> state``."""
         key = ("slice", self._mode)
         fn = self._loop_cache.get(key)
         if fn is None:
             cond, body = self._loop_fns(self._mode)
 
-            def one(state, budget):
+            def one(layouts, state, budget):
                 def scond(s):
                     return jnp.logical_and(cond(s[:-1]), s[-1] < budget)
 
                 def sbody(s):
-                    return (*body(s[:-1]), s[-1] + 1)
+                    return (*body(layouts, s[:-1]), s[-1] + 1)
 
                 out = jax.lax.while_loop(
                     scond, sbody, (*state, jnp.asarray(0, jnp.int32)))
                 return BatchLaneState(*out[:-1])
 
-            fn = jax.jit(jax.vmap(one, in_axes=(0, None)))
+            fn = jax.jit(jax.vmap(one, in_axes=(None, 0, None)))
             self._loop_cache[key] = fn
-        return fn(state, jnp.asarray(budget, jnp.int32))
+        return fn
 
     def lane_done(self, state: BatchLaneState) -> np.ndarray:
         """Host bool (k,): lane converged (empty frontier or max_iters)."""
@@ -950,9 +968,6 @@ class CompiledGraphProgram:
 # ---------------------------------------------------------------------------
 
 
-_ROW_REDUCE = pull_bitmap_kernel._ROW_REDUCE
-
-
 def _flat_message_mode(ir: SuperstepIR, fused: FusedGatherReduceOp,
                        program, dtype) -> str:
     """Pick the flat sweep's per-edge message form (all bit-identical):
@@ -976,17 +991,67 @@ def _flat_message_mode(ir: SuperstepIR, fused: FusedGatherReduceOp,
     return "classic"
 
 
+def _slot_major(rows) -> np.ndarray:
+    """``(R, 8)`` host rows → ``(8, R)`` device layout.
+
+    On a TPU the minor dimension tiles to 128 lanes, so a gather whose
+    index array is ``(R, 8)`` materializes a 16×-padded ``(R, 128)``
+    intermediate (measured by a v5e compile: 4.8 GiB of temporaries at
+    R = 2^23); slot-major keeps the long axis minor and the sweep's
+    temporaries at the logical size.
+    """
+    return np.ascontiguousarray(np.asarray(rows).T)
+
+
+def _pull_device_arrays(pplan: G.PullBitmapPlan) -> dict:
+    """Host arrays of the dense pull plane, as the engine takes them.
+
+    ``dst``/``wgt`` are the flat width-8 view slot-major (``(8, R8p)``,
+    PAD baked to V); ``plan`` keeps the combine and block maps, with the
+    row-major flat arrays dropped so they never reach the device.
+    """
+    return {"dst": _slot_major(pplan.flat_dst_safe),
+            "wgt": _slot_major(pplan.flat_wgt),
+            "plan": dataclasses.replace(pplan, flat_dst=None,
+                                        flat_dst_safe=None, flat_wgt=None)}
+
+
+def _fwd_device_arrays(fe: G.ForwardELL) -> dict:
+    """Host arrays of the forward ELL as the engine takes them: edge slots
+    slot-major (``(W, R)``, see :func:`_slot_major`)."""
+    return {"row_src": fe.row_src, "dst": _slot_major(fe.dst),
+            "wgt": _slot_major(fe.weights),
+            "rows_per_vertex": fe.rows_per_vertex}
+
+
+def _fwd_pe_device_arrays(sfe: G.ShardedForwardELL) -> dict:
+    """Per-PE forward-ELL intervals, slot-major within each PE
+    (``(pes, W, Rp)``); the leading axis is what ``P("pe")`` splits."""
+    def per_pe(a):
+        return np.ascontiguousarray(np.swapaxes(np.asarray(a), 1, 2))
+    return {"row_src": sfe.row_src, "dst": per_pe(sfe.dst),
+            "wgt": per_pe(sfe.weights), "row_valid": sfe.row_valid}
+
+
+def _pallas_rows(pull: dict, num_vertices: int):
+    """Row-major PAD-form ``(R8p, 8)`` edge block for the Pallas kernel
+    (transposed in the staged program; the XLA sweep reads slot-major)."""
+    dst = pull["dst"]
+    return (jnp.where(dst == num_vertices, G.PAD, dst).T, pull["wgt"].T)
+
+
 def _emit_dense_pull_reduce(ir: SuperstepIR, fused: FusedGatherReduceOp,
-                            pplan: G.PullBitmapPlan, out_deg,
+                            pplan: G.PullBitmapPlan,
                             schedule: ScheduleConfig, use_pallas: bool):
     """Emit the dense pull partial-reduce module over the flat width-8 view.
 
-    The module contract is unchanged — ``(values, active) → (red, got)``
-    per-vertex tables — but the sweep is rebuilt around
-    :class:`~repro.core.graph.PullBitmapPlan`'s flat layout:
+    The module contract is ``(layouts, values, active) → (red, got)``
+    per-vertex tables; the sweep is rebuilt around
+    :class:`~repro.core.graph.PullBitmapPlan`'s flat layout, which the
+    device holds slot-major (``layouts["pull"]``, see :func:`_slot_major`):
 
-    * ONE uniform ``(R8, 8)`` gather + row-reduce instead of eight
-      per-bucket kernels of different widths (~1.2 vs ~5.8 ns/slot
+    * ONE uniform gather + per-sub-row reduce over the 8 slots instead of
+      eight per-bucket kernels of different widths (~1.2 vs ~5.8 ns/slot
       measured on XLA:CPU — narrow uniform rows vectorize, tiny buckets
       stop paying per-op dispatch), with the message form picked by
       :func:`_flat_message_mode`;
@@ -1002,7 +1067,7 @@ def _emit_dense_pull_reduce(ir: SuperstepIR, fused: FusedGatherReduceOp,
     ``edge_block_reduce`` kernel (same VMEM vertex-cache addressing,
     uniform width 8) and only the combine cascade runs in XLA.
     Bit-exactness: min/max/integer-add are order-free; float-add keeps a
-    deterministic per-vertex lane order (sub-rows reduce in lane order,
+    deterministic per-vertex lane order (sub-rows reduce in slot order,
     then fold in ascending sub-row order), reassociated relative to the
     pre-flat bucketed sweep within normal reduction noise.
     """
@@ -1014,10 +1079,11 @@ def _emit_dense_pull_reduce(ir: SuperstepIR, fused: FusedGatherReduceOp,
     gather_module = fused.gather.module
     gather_fn = fused.gather.fn
     mode = _flat_message_mode(ir, fused, program, dtype)
-    rop = _ROW_REDUCE[op]
+    rop = ROW_REDUCE[op]
 
-    def sub_sweep(values, active, dst_blk, wgt_blk):
-        """Per-sub-row (red, any) over a flat edge block (any is lazy).
+    def sub_sweep(values, active, out_deg, dst_blk, wgt_blk):
+        """Per-sub-row (red, any) over a slot-major ``(8, n)`` edge block
+        (any is lazy).
 
         ``dst_blk`` uses the *safe* index form (PAD baked to V, the
         message/live tables' always-identity dummy slot) so the hot loop
@@ -1047,40 +1113,42 @@ def _emit_dense_pull_reduce(ir: SuperstepIR, fused: FusedGatherReduceOp,
                 live = live & active[safe0]
             msg = jnp.where(live, msg.astype(dtype),
                             jnp.asarray(ident, dtype))
-            return rop(msg, axis=1), jnp.any(live, axis=1)
+            return rop(msg, axis=0), jnp.any(live, axis=0)
 
         # lazy any-chain: a separate uint8 gather XLA can DCE wholesale
         def sub_any():
             if program.mask_inactive:
                 live_t = jnp.concatenate([active.astype(jnp.uint8),
                                           jnp.zeros((1,), jnp.uint8)])
-                return jnp.any(live_t[dst_blk], axis=1)
-            return jnp.any(dst_blk != V, axis=1)
+                return jnp.any(live_t[dst_blk], axis=0)
+            return jnp.any(dst_blk != V, axis=0)
 
-        return rop(msg, axis=1), sub_any()
+        return rop(msg, axis=0), sub_any()
 
-    def partial_reduce(values, active):
+    def partial_reduce(layouts, values, active):
+        pull, out_deg = layouts["pull"], layouts["deg"]
         if use_pallas:
+            nbr, wgt = _pallas_rows(pull, V)
             sub_red, sub_anyv = kops.edge_block_reduce(
-                pplan.flat_dst, pplan.flat_wgt, values, out_deg, active,
+                nbr, wgt, values, out_deg, active,
                 gather=gather_module, reduce=op,
                 mask_inactive=program.mask_inactive,
                 block_rows=schedule.block_rows)
         else:
-            sub_red, sub_anyv = sub_sweep(values, active,
-                                          pplan.flat_dst_safe,
-                                          pplan.flat_wgt)
-        red = pull_bitmap_kernel.subrow_combine(sub_red, pplan, ident, op,
-                                                dtype)
+            sub_red, sub_anyv = sub_sweep(values, active, out_deg,
+                                          pull["dst"], pull["wgt"])
+        red = pull_bitmap_kernel.subrow_combine(sub_red, pull["plan"],
+                                                ident, op, dtype)
         got = pull_bitmap_kernel.subrow_combine(
-            sub_anyv.astype(jnp.int8), pplan, 0, "max", jnp.int8) != 0
+            sub_anyv.astype(jnp.int8), pull["plan"], 0, "max",
+            jnp.int8) != 0
         return red, got
 
     return partial_reduce, sub_sweep
 
 
 def _emit_pull_bitmap(ir: SuperstepIR, fstep: FusedSuperstepOp,
-                      pplan: G.PullBitmapPlan, fe: G.ForwardELL, out_deg,
+                      pplan: G.PullBitmapPlan, fe: G.ForwardELL,
                       reduce_module, sub_sweep, use_pallas: bool,
                       num_edges: int, schedule: ScheduleConfig):
     """Emit the fused bitmap-frontier pull superstep (block-skipping sweep).
@@ -1113,6 +1181,9 @@ def _emit_pull_bitmap(ir: SuperstepIR, fstep: FusedSuperstepOp,
     * the touched table doubles as the ``got`` mask (bit-identical to the
       dense module's: both mean "some live in-edge"), and identity-
       fixpoint applies (``fstep.touched_free``) skip the mask entirely.
+
+    Reads ``layouts["pull"]`` (slot-major flat view + block maps) and
+    ``layouts["fwd"]`` (the forward ELL of the touched pre-pass).
     """
     fused = fstep.fused
     apply_fn = fstep.apply.fn
@@ -1134,15 +1205,17 @@ def _emit_pull_bitmap(ir: SuperstepIR, fstep: FusedSuperstepOp,
         return new, nxt, (swept_e, swept_b,
                           jnp.asarray(b_total, jnp.int32) - swept_b)
 
-    def sweep_gathered(cap, touched, live, values, active):
+    def sweep_gathered(layouts, cap, touched, live, values, active):
         """XLA path: compact live block ids, gather their sub-rows."""
+        pull = layouts["pull"]
         nb = b_total
         selb, okb = G.bitmap_select(G.pack_bits(live), cap)
         rows = (selb[:, None] * br
                 + jnp.arange(br, dtype=jnp.int32)[None, :]).reshape(-1)
         okr = jnp.repeat(okb, br)
-        dst_blk = jnp.where(okr[:, None], pplan.flat_dst_safe[rows], V)
-        sub, _ = sub_sweep(values, active, dst_blk, pplan.flat_wgt[rows])
+        dst_blk = jnp.where(okr[None, :], pull["dst"][:, rows], V)
+        sub, _ = sub_sweep(values, active, layouts["deg"], dst_blk,
+                           pull["wgt"][:, rows])
         # expand back to the full sub-row space GATHER-side: a tiny
         # inverse-map scatter (cap entries) + two cheap gathers, instead
         # of scatter-setting cap·block_rows sub-results (~60 ns/el — it
@@ -1155,10 +1228,10 @@ def _emit_pull_bitmap(ir: SuperstepIR, fstep: FusedSuperstepOp,
         sub_idx = jnp.arange(r8p, dtype=jnp.int32)
         slot = inv[sub_idx // br]              # cap → the identity tail
         sub_full = buffer[slot * br + sub_idx % br]
-        return pull_bitmap_kernel.subrow_combine(sub_full, pplan,
+        return pull_bitmap_kernel.subrow_combine(sub_full, pull["plan"],
                                                  ident, op, dtype)
 
-    def sweep_pallas(touched, live, values, active):
+    def sweep_pallas(layouts, touched, live, values, active):
         """Pallas path: full grid, per-block in-kernel early-out.
 
         Skipping happens at the Pallas grid's ``schedule.block_rows``
@@ -1167,16 +1240,18 @@ def _emit_pull_bitmap(ir: SuperstepIR, fstep: FusedSuperstepOp,
         blocks that actually ran — the two paths are bit-exact in results
         but may legitimately differ in their skip counts.
         """
-        t8 = touched[pplan.owner8] != 0
+        pull = layouts["pull"]
+        t8 = touched[pull["plan"].owner8] != 0
         grid_br = schedule.block_rows
         pad = (-r8p) % grid_br
         grid_live = jnp.pad(t8, (0, pad)).reshape(-1, grid_br).any(axis=1)
+        nbr, wgt = _pallas_rows(pull, V)
         sub_red, _ = kops.edge_block_reduce(
-            pplan.flat_dst, pplan.flat_wgt, values, out_deg, active,
+            nbr, wgt, values, layouts["deg"], active,
             gather=fused.gather.module, reduce=op, mask_inactive=True,
             block_rows=grid_br, block_live=grid_live)
-        red = pull_bitmap_kernel.subrow_combine(sub_red, pplan, ident, op,
-                                                dtype)
+        red = pull_bitmap_kernel.subrow_combine(sub_red, pull["plan"],
+                                                ident, op, dtype)
         if grid_br % br == 0:
             fine_live = jnp.repeat(grid_live, grid_br // br)[:b_total]
         else:    # odd grid: account conservatively at fine granularity
@@ -1206,31 +1281,32 @@ def _emit_pull_bitmap(ir: SuperstepIR, fstep: FusedSuperstepOp,
         """
         block_cap = min(4 * cap, b_total)
 
-        def f(values, active):
-            touched = kops.touched_frontier(
-                fe.row_src, fe.dst, active, num_rows=fe.num_rows,
+        def f(layouts, values, active):
+            fwd, plan_d = layouts["fwd"], layouts["pull"]["plan"]
+            touched = pull_bitmap_kernel.touched_table(
+                fwd["row_src"], fwd["dst"], active, num_rows=fe.num_rows,
                 capacity=cap, num_vertices=V)
-            live = pull_bitmap_kernel.block_liveness(touched, pplan.owner8,
+            live = pull_bitmap_kernel.block_liveness(touched, plan_d.owner8,
                                                      br)
             got = touched[:V] != 0
 
             if use_pallas:
-                red, fine_live = sweep_pallas(touched, live, values,
-                                              active)
-                swept_e = jnp.sum(jnp.where(fine_live, pplan.block_edges,
+                red, fine_live = sweep_pallas(layouts, touched, live,
+                                              values, active)
+                swept_e = jnp.sum(jnp.where(fine_live, plan_d.block_edges,
                                             0))
                 return finish(values, red, got, swept_e,
                               jnp.sum(fine_live.astype(jnp.int32)))
 
             def compacted(values, active):
-                red = sweep_gathered(block_cap, touched, live, values,
-                                     active)
-                swept_e = jnp.sum(jnp.where(live, pplan.block_edges, 0))
+                red = sweep_gathered(layouts, block_cap, touched, live,
+                                     values, active)
+                swept_e = jnp.sum(jnp.where(live, plan_d.block_edges, 0))
                 return finish(values, red, got, swept_e,
                               jnp.sum(live.astype(jnp.int32)))
 
             def overflow(values, active):
-                red, got_d = reduce_module(values, active)
+                red, got_d = reduce_module(layouts, values, active)
                 return finish(values, red, got_d,
                               jnp.asarray(num_edges, jnp.int32),
                               jnp.asarray(b_total, jnp.int32))
@@ -1240,37 +1316,38 @@ def _emit_pull_bitmap(ir: SuperstepIR, fstep: FusedSuperstepOp,
                                 values, active)
         return f
 
-    def wide(values, active):
-        red, got = reduce_module(values, active)
+    def wide(layouts, values, active):
+        red, got = reduce_module(layouts, values, active)
         return finish(values, red, got, jnp.asarray(num_edges, jnp.int32),
                       jnp.asarray(b_total, jnp.int32))
 
     @jax.jit
-    def pull_superstep(values, active):
+    def pull_superstep(layouts, values, active):
         # single-switch routing on m_f alone: the frontier's out-edge
         # count bounds every dynamic size in a compacted superstep (live
         # forward-ELL rows, touched vertices, live blocks), so the tier
         # whose capacity covers m_f is guaranteed sufficient — and a
         # frontier too wide for the largest tier takes the dense sweep
         # without ever paying the pre-pass
-        m_f = jnp.sum(jnp.where(active, out_deg, 0))
+        m_f = jnp.sum(jnp.where(active, layouts["deg"], 0))
         tier = (m_f > caps[0]).astype(jnp.int32) \
             + (m_f > caps[1]).astype(jnp.int32)
         return jax.lax.switch(tier, [narrow(caps[0]), narrow(caps[1]),
-                                     wide], values, active)
+                                     wide], layouts, values, active)
 
     return pull_superstep, caps
 
 
 def _wrap_superstep_stats(superstep, num_edges: int):
-    """Lift a ``(values, active) → (values, active)`` superstep to the run
-    loop's stats-carrying contract: non-bitmap pull planes sweep all E
-    edges every superstep and have no block accounting (zeros)."""
+    """Lift a ``(layouts, values, active) → (values, active)`` superstep
+    to the run loop's stats-carrying contract: non-bitmap pull planes
+    sweep all E edges every superstep and have no block accounting
+    (zeros)."""
     z = jnp.asarray(0, jnp.int32)
     e = jnp.asarray(num_edges, jnp.int32)
 
-    def wrapped(values, active):
-        new, nxt = superstep(values, active)
+    def wrapped(layouts, values, active):
+        new, nxt = superstep(layouts, values, active)
         return new, nxt, (e, z, z)
 
     return wrapped
@@ -1278,8 +1355,7 @@ def _wrap_superstep_stats(superstep, num_edges: int):
 
 def _emit_segment_scan_reduce(ir: SuperstepIR, fused: FusedGatherReduceOp,
                               reverse_coo: tuple, num_vertices: int,
-                              num_edges: int, out_deg,
-                              splan: SchedulePlan):
+                              num_edges: int, splan: SchedulePlan):
     """Emit the sparse chunk-streamed partial-reduce module.
 
     ``pipelines`` → ``lax.scan`` over edge chunks (bounds the live working
@@ -1290,6 +1366,12 @@ def _emit_segment_scan_reduce(ir: SuperstepIR, fused: FusedGatherReduceOp,
     ``SchedulePlan.describe()`` reports.  ``reverse_coo`` is the cached
     COO of the transposed graph
     (:meth:`~repro.core.preprocess.GraphLayouts.reverse_coo`).
+
+    Returns ``(scan_chunks, host_chunks, nchunk)``: ``scan_chunks(chunks,
+    out_deg, values, active, varying=False)`` reduces the given chunk
+    arrays (all of them, or one PE's slice under ``shard_map``), and
+    ``host_chunks`` are the ``(nchunk, chunk_size)`` host arrays the
+    translator places as ``layouts["coo"]``.
     """
     program = ir.program
     dtype = ir.value_dtype
@@ -1300,19 +1382,18 @@ def _emit_segment_scan_reduce(ir: SuperstepIR, fused: FusedGatherReduceOp,
     gather_fn = fused.gather.fn
 
     # COO of the reversed graph: edge (u → v) appears as (dst=v, src=u)
-    seg_dst, src, wts = reverse_coo            # seg: receiving vertex
+    seg_dst, src, wts = (np.asarray(a) for a in reverse_coo)
     nchunk = splan.num_chunks
     csize = splan.chunk_size
     pad = nchunk * csize - E
-    PADV = jnp.iinfo(jnp.int32).max
-    seg_c = jnp.pad(seg_dst, (0, pad), constant_values=PADV).reshape(nchunk, csize)
-    src_c = jnp.pad(src, (0, pad)).reshape(nchunk, csize)
-    wts_c = jnp.pad(wts, (0, pad)).reshape(nchunk, csize)
+    PADV = np.iinfo(np.int32).max
+    host_chunks = (
+        np.pad(seg_dst, (0, pad),
+               constant_values=PADV).reshape(nchunk, csize),
+        np.pad(src, (0, pad)).reshape(nchunk, csize),
+        np.pad(wts, (0, pad)).reshape(nchunk, csize))
 
-    def partial_reduce(values, active, chunks=None):
-        my_seg, my_src, my_wts = chunks if chunks is not None \
-            else (seg_c, src_c, wts_c)
-
+    def scan_chunks(chunks, out_deg, values, active, varying=False):
         def chunk(carry, xs):
             red_table, got_table = carry
             seg, srcs, ws = xs
@@ -1336,45 +1417,48 @@ def _emit_segment_scan_reduce(ir: SuperstepIR, fused: FusedGatherReduceOp,
             return (red_table, got_table), None
 
         init = (jnp.full((V,), ident, dtype), jnp.zeros((V,), bool))
-        if chunks is not None:   # per-PE slices are pe-varying
-            init = jax.tree.map(lambda a: pvary(a, ("pe",)), init)
-        (red_table, got_table), _ = jax.lax.scan(
-            chunk, init, (my_seg, my_src, my_wts))
+        if varying:   # per-PE slices are pe-varying
+            init = jax.tree.map(
+                lambda a: jax.lax.pcast(a, ("pe",), to="varying"), init)
+        (red_table, got_table), _ = jax.lax.scan(chunk, init, chunks)
         return red_table, got_table
 
-    return partial_reduce, (seg_c, src_c, wts_c), nchunk
+    return scan_chunks, host_chunks, nchunk
 
 
 def _emit_push_scatter(ir: SuperstepIR, push_op: PushScatterOp, g: G.Graph,
-                       out_deg, splan: SchedulePlan):
+                       splan: SchedulePlan):
     """Emit the legacy chunk-streamed push scatter (``layout='coo_chunks'``).
 
     Streams the *forward* CSR's COO chunks (no transpose — ``g`` already
     holds out-edges), scattering messages from active sources with
     ``at[].add/min/max``; chunks with no active source are skipped via
     ``lax.cond``.  Kept for the sparse backend, which builds no forward
-    ELL; the dense backend uses :func:`_emit_push_ell` instead.
+    ELL; the dense backend uses :func:`_emit_push_ell` instead.  Returns
+    ``(partial_reduce, chunks)``; the translator places ``chunks`` as
+    ``layouts["push_coo"]``.
     """
     dtype = ir.value_dtype
     V = g.num_vertices
     src, dst, wts = G.coo_arrays(g)
-    dst_c, src_c, wgt_c = push_kernel.chunk_coo(
-        dst, src, wts, num_chunks=splan.num_chunks)
+    chunks = push_kernel.chunk_coo(dst, src, wts,
+                                   num_chunks=splan.num_chunks)
     ident = push_op.reduce.identity
     gather_fn = push_op.gather.fn
     reduce_op = push_op.reduce.op
 
-    def partial_reduce(values, active):
+    def partial_reduce(layouts, values, active):
+        dst_c, src_c, wgt_c = layouts["push_coo"]
         return push_kernel.push_scatter_reduce(
-            dst_c, src_c, wgt_c, values, out_deg, active,
+            dst_c, src_c, wgt_c, values, layouts["deg"], active,
             gather_fn=gather_fn, reduce=reduce_op, identity=ident,
             num_vertices=V, dtype=dtype)
 
-    return partial_reduce
+    return partial_reduce, chunks
 
 
 def _emit_push_ell(ir: SuperstepIR, push_op: PushScatterOp,
-                   fe: G.ForwardELL, out_deg, apply_fn, pull_reduce_module,
+                   fe: G.ForwardELL, apply_fn, pull_reduce_module,
                    use_pallas: bool):
     """Emit the frontier-compacted forward-ELL push superstep.
 
@@ -1390,8 +1474,9 @@ def _emit_push_ell(ir: SuperstepIR, push_op: PushScatterOp,
     The compacted branches apply the reduced table *everywhere* and skip
     the touched mask entirely — sound because the fusion pass binds the
     ``fwd_ell`` layout only after probing ``apply(x, identity) == x``
-    (untouched vertices are fixpoints).  Returns ``(push_superstep,
-    tiers)``.
+    (untouched vertices are fixpoints).  Reads ``layouts["fwd"]``.
+    Returns ``(push_superstep, tiers, live_rows)``, where ``live_rows``
+    is the run loop's ``(1,)`` live-row counter.
     """
     dtype = ir.value_dtype
     V = fe.num_vertices
@@ -1400,14 +1485,19 @@ def _emit_push_ell(ir: SuperstepIR, push_op: PushScatterOp,
     gather_module = push_op.gather.module
     reduce_op = push_op.reduce.op
     tiers = push_capacity_tiers(fe.num_rows)
-    rows_per_v = fe.rows_per_vertex
     interpret = jax.default_backend() != "tpu"
 
+    def live_rows(layouts, active):
+        rows_per_v = layouts["fwd"]["rows_per_vertex"]
+        return jnp.sum(jnp.where(active, rows_per_v, 0))[None]
+
     def compacted_branch(capacity):
-        def branch(values, active):
+        def branch(layouts, values, active):
+            fwd = layouts["fwd"]
             red, _ = push_ell_kernel.push_ell_reduce(
-                fe.row_src, fe.dst, fe.weights, values, out_deg, active,
-                num_rows=fe.num_rows, capacity=capacity,
+                fwd["row_src"], fwd["dst"], fwd["wgt"], values,
+                layouts["deg"],
+                active, num_rows=fe.num_rows, capacity=capacity,
                 gather_fn=gather_fn, reduce=reduce_op, identity=ident,
                 num_vertices=V, dtype=dtype, gather_module=gather_module,
                 use_pallas=use_pallas, interpret=interpret,
@@ -1416,28 +1506,28 @@ def _emit_push_ell(ir: SuperstepIR, push_op: PushScatterOp,
             return new, new != values
         return branch
 
-    def dense_fallback(values, active):
+    def dense_fallback(layouts, values, active):
         # the pull module's masked sweep (bit-identical reduce); the
         # fwd_ell layout already guarantees an identity-fixpoint apply,
         # so applying everywhere matches take-if-touched bit-for-bit and
         # the unused `got` chain is dead code XLA deletes
-        red, _ = pull_reduce_module(values, active)
+        red, _ = pull_reduce_module(layouts, values, active)
         new = apply_fn(values, red)
         return new, new != values
 
     branches = [compacted_branch(c) for c in tiers] + [dense_fallback]
 
     @jax.jit
-    def push_superstep(values, active):
-        r_f = jnp.sum(jnp.where(active, rows_per_v, 0))
+    def push_superstep(layouts, values, active):
+        r_f = live_rows(layouts, active)[0]
         tier = sum((r_f > c).astype(jnp.int32) for c in tiers)
-        return jax.lax.switch(tier, branches, values, active)
+        return jax.lax.switch(tier, branches, layouts, values, active)
 
-    return push_superstep, tiers
+    return push_superstep, tiers, live_rows
 
 
 def _emit_push_ell_sharded(ir: SuperstepIR, push_op: PushScatterOp,
-                           sfe: G.ShardedForwardELL, out_deg, apply_fn,
+                           sfe: G.ShardedForwardELL, apply_fn,
                            pull_reduce_module, use_pallas: bool, mesh,
                            xop: ExchangeOp):
     """Emit the multi-PE frontier-compacted push superstep (``shard_map``).
@@ -1466,7 +1556,9 @@ def _emit_push_ell_sharded(ir: SuperstepIR, push_op: PushScatterOp,
 
     Returns ``(push_superstep, tiers, live_rows_per_pe)`` where the last
     is the ``(pes,)`` live-row counter the run loop uses for the
-    tier/fallback stat and the per-PE load-balance stats.
+    tier/fallback stat and the per-PE load-balance stats.  Reads
+    ``layouts["fwd_pe"]``, which the translator places on ``P("pe")`` so
+    each PE holds only its own interval.
     """
     dtype = ir.value_dtype
     V = sfe.num_vertices
@@ -1479,12 +1571,13 @@ def _emit_push_ell_sharded(ir: SuperstepIR, push_op: PushScatterOp,
     collective = _COLLECTIVES[xop.collective]
     rp = sfe.rows_per_pe_max
 
-    def live_rows_per_pe(active):
+    def live_rows_per_pe(layouts, active):
         """(pes,) live rows — per-PE partials of the frontier occupancy."""
-        return jnp.sum(active[sfe.row_src] & sfe.row_valid,
+        fwd = layouts["fwd_pe"]
+        return jnp.sum(active[fwd["row_src"]] & fwd["row_valid"],
                        axis=1).astype(jnp.int32)
 
-    def pe_partial(rs, dstb, wgtb, valid, values, active):
+    def pe_partial(rs, dstb, wgtb, valid, out_deg, values, active):
         rs, dstb, wgtb, valid = rs[0], dstb[0], wgtb[0], valid[0]
         live = active[rs] & valid       # interval-local live-row mask
         r_f = jnp.sum(live.astype(jnp.int32))
@@ -1506,44 +1599,47 @@ def _emit_push_ell_sharded(ir: SuperstepIR, push_op: PushScatterOp,
         # disjoint partials: the reduce-matched collective is exact
         return collective(red, "pe")
 
-    def sharded_compacted(values, active):
-        # unchecked: 0.4.x's replication checker mis-types this body when
-        # run_batch vmaps the loop (see shard_map_unchecked); the output
-        # is genuinely replicated — the collective runs unconditionally
+    def sharded_compacted(layouts, values, active):
+        # unchecked: the vma checker cannot type the per-PE switch before
+        # the collective, nor this body when run_batch vmaps the loop
+        # (see shard_map_unchecked); the output is genuinely replicated —
+        # the collective runs unconditionally
+        fwd = layouts["fwd_pe"]
         red = shard_map_unchecked(pe_partial, mesh=mesh,
                                   in_specs=(P("pe"), P("pe"), P("pe"),
-                                            P("pe"), P(), P()),
+                                            P("pe"), P(), P(), P()),
                                   out_specs=P())(
-            sfe.row_src, sfe.dst, sfe.weights, sfe.row_valid,
-            values, active)
+            fwd["row_src"], fwd["dst"], fwd["wgt"], fwd["row_valid"],
+            layouts["deg"], values, active)
         new = apply_fn(values, red)
         return new, new != values
 
-    def dense_fallback(values, active):
+    def dense_fallback(layouts, values, active):
         # the pull module's masked sweep, replicated (no exchange); the
         # identity-fixpoint apply lets it skip the touched mask (as in
         # the single-PE engine)
-        red, _ = pull_reduce_module(values, active)
+        red, _ = pull_reduce_module(layouts, values, active)
         new = apply_fn(values, red)
         return new, new != values
 
     @jax.jit
-    def push_superstep(values, active):
-        wide = jnp.max(live_rows_per_pe(active)) > tiers[-1]
+    def push_superstep(layouts, values, active):
+        wide = jnp.max(live_rows_per_pe(layouts, active)) > tiers[-1]
         return jax.lax.cond(wide, dense_fallback, sharded_compacted,
-                            values, active)
+                            layouts, values, active)
 
     return push_superstep, tiers, live_rows_per_pe
 
 
-def _emit_exchange(xop: ExchangeOp, partial_reduce, chunk_arrays,
-                   nchunk: int, mesh, quantized: bool = False,
-                   num_vertices: int = 0):
+def _emit_exchange(xop: ExchangeOp, scan_chunks, mesh,
+                   quantized: bool = False, num_vertices: int = 0):
     """Emit the cross-PE combine around the partial-reduce module.
 
-    Each PE owns an edge-chunk slice (paper: edge partitions per PE);
-    vertex tables replicate and combine with the reduce-matched collective —
-    psum for 'add' is only correct because the edge sets are disjoint per PE.
+    Each PE owns an edge-chunk slice (paper: edge partitions per PE):
+    ``layouts["coo"]`` is placed on ``P("pe")``, so every PE scans only
+    its own ``nchunk / pes`` chunks.  Vertex tables replicate and combine
+    with the reduce-matched collective — psum for 'add' is only correct
+    because the edge sets are disjoint per PE.
 
     ``quantized`` swaps the full-precision psum for
     :meth:`~repro.core.comm.CommManager.quantized_psum` (int8 wire format
@@ -1559,8 +1655,6 @@ def _emit_exchange(xop: ExchangeOp, partial_reduce, chunk_arrays,
     int8 ring form is kept.  Both forms are bit-exact (OR of packed words
     ≡ pmax of the unpacked mask).
     """
-    seg_c, src_c, wts_c = chunk_arrays
-    k_per_pe = nchunk // xop.pes
     collective = _COLLECTIVES[xop.collective]
     pack_mask = xop.pes < 16
     if quantized:
@@ -1568,13 +1662,10 @@ def _emit_exchange(xop: ExchangeOp, partial_reduce, chunk_arrays,
         collective = functools.partial(CommManager.quantized_psum,
                                        pes=xop.pes)
 
-    def sharded_reduce(values, active):
-        def pe_body(values, active):
-            pe = jax.lax.axis_index("pe")
-            chunks = tuple(
-                jax.lax.dynamic_slice_in_dim(c, pe * k_per_pe, k_per_pe, 0)
-                for c in (seg_c, src_c, wts_c))
-            red, got = partial_reduce(values, active, chunks)
+    def sharded_reduce(layouts, values, active):
+        def pe_body(chunks, out_deg, values, active):
+            red, got = scan_chunks(chunks, out_deg, values, active,
+                                   varying=True)
             red = collective(red, "pe")
             if pack_mask:
                 words = CommManager.bitmap_or(G.pack_bits(got), "pe",
@@ -1585,14 +1676,14 @@ def _emit_exchange(xop: ExchangeOp, partial_reduce, chunk_arrays,
             return red, got
 
         # unchecked: the quantized combine ends in all_gather + local sum,
-        # whose replicated-ness 0.4.x's static checker cannot infer (and
-        # the checker also mis-types vmapped shard_maps — see
-        # shard_map_unchecked).  The outputs are genuinely replicated:
-        # every PE computes the same combine of the same gathered parts.
+        # whose replicated-ness the vma checker cannot infer (nor that of
+        # a vmapped shard_map — see shard_map_unchecked).  The outputs
+        # are genuinely replicated: every PE computes the same combine of
+        # the same gathered parts.
         return shard_map_unchecked(pe_body, mesh=mesh,
-                                   in_specs=(P(), P()),
+                                   in_specs=((P("pe"),) * 3, P(), P(), P()),
                                    out_specs=(P(), P()))(
-            values, active)
+            layouts["coo"], layouts["deg"], values, active)
 
     return sharded_reduce
 
@@ -1651,7 +1742,7 @@ def translate(
     schedule: ScheduleConfig | None = None,
     comm: CommManager | None = None,
     *,
-    use_pallas: bool | None = None,
+    use_pallas: bool = False,
     aot_compile: bool = True,
     dump_passes: bool = False,
     validate: bool = False,
@@ -1695,8 +1786,6 @@ def translate(
     splan: SchedulePlan = plan(schedule, num_vertices=g.num_vertices,
                                num_edges=g.num_edges,
                                fixed_partitions=getattr(g, "partitions", None))
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
 
     # out-of-core dispatch: a multi-partition plan, or a graph source that
     # is not resident (a partition container), stages onto the streamed
@@ -1756,7 +1845,7 @@ def translate(
         lay = preprocess.layouts_for(g)
         staged = _stage(program, ir, g, lay, schedule, splan, use_pallas,
                         fstep, fused, apply_op, frontier_op, exchange_op,
-                        push_op if policy.mode != "pull" else None)
+                        push_op if policy.mode != "pull" else None, comm)
         preprocess_s = staged.pop("preprocess_s")
         emit_s = staged.pop("emit_s")
         cached = False
@@ -1769,6 +1858,7 @@ def translate(
     superstep = staged["superstep"]
     push_superstep = staged["push_superstep"]
     init_state = staged["init_state"]
+    layouts = staged["device_layouts"]
     max_iters = schedule.superstep_budget(program.max_iters, V)
 
     # AOT compile so translation time includes staging (paper's TT metric).
@@ -1776,10 +1866,11 @@ def translate(
     # jit call cache, which the staging cache then reuses across repeats.
     t_aot0 = time.perf_counter()
     if aot_compile and not staged["aot_done"]:
-        v0, a0 = init_state(roots=0 if program.frontier == "changed" else None)
-        jax.block_until_ready(superstep(v0, a0))
+        v0, a0 = init_state(layouts, roots=0 if program.frontier == "changed"
+                            else None)
+        jax.block_until_ready(superstep(layouts, v0, a0))
         if push_superstep is not None:
-            jax.block_until_ready(push_superstep(v0, a0))
+            jax.block_until_ready(push_superstep(layouts, v0, a0))
         staged["aot_done"] = True
     aot_s = time.perf_counter() - t_aot0
 
@@ -1831,10 +1922,9 @@ def translate(
         pull_blocks_total=staged["pull_blocks_total"],
     )
     return CompiledGraphProgram(
-        superstep, init_state, report, max_iters,
-        push_superstep=push_superstep, direction=policy,
-        out_degrees=staged["out_degrees"], num_vertices=V,
-        num_edges=g.num_edges, rows_per_vertex=staged["rows_per_vertex"],
+        superstep, init_state, report, max_iters, layouts=layouts,
+        push_superstep=push_superstep, direction=policy, num_vertices=V,
+        num_edges=g.num_edges,
         push_tiers=staged["push_tiers"], loop_cache=staged["loop_cache"],
         push_rf_fn=staged["push_rf_fn"],
         push_stat_pes=staged["push_stat_pes"], comm=comm,
@@ -1845,19 +1935,40 @@ def translate(
         fingerprints_fn=lambda: ckpt.run_fingerprints(program, g, schedule))
 
 
+def _place_layouts(host: dict, comm: CommManager, mesh) -> dict:
+    """Place the staged functions' layout arrays, once, through ``comm``.
+
+    One device holds everything when ``mesh`` is None.  Under a multi-PE
+    plan the per-PE partitions (``"fwd_pe"``, ``"coo"``) go on
+    ``P("pe")`` — each PE holds only its own interval or chunk slice — and
+    the rest is replicated on the mesh.  Staged programs take these as jit
+    *arguments*, never as baked HLO constants.
+    """
+    if mesh is None:
+        return comm.transport(host)
+    split = {k: host.pop(k) for k in ("fwd_pe", "coo") if k in host}
+    placed = comm.transport(host, P(), mesh=mesh)
+    placed.update(comm.transport(split, P("pe"), mesh=mesh))
+    return placed
+
+
 def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
-           apply_op, frontier_op, exchange_op, push_op):
+           apply_op, frontier_op, exchange_op, push_op, comm):
     """Stage 3 proper: walk the optimized IR, emit the jitted supersteps.
 
     Returns the staging-cache entry; graph-derived layouts come from
-    ``lay`` (built on first use, memoized per graph), and the entry's
+    ``lay`` (built on first use, memoized per graph) and are placed on the
+    device here (the entry's ``device_layouts``), and the entry's
     ``preprocess_s`` records only the layout seconds spent *this* call.
     """
     pre_before = sum(lay.build_times_s.values())
     t_emit0 = time.perf_counter()
     dtype = ir.value_dtype
     V = g.num_vertices
-    out_deg = g.out_degrees.astype(jnp.int32)
+    # the graph arrays every staged function takes as its first argument
+    # (placed once, below); "coo"/"fwd_pe" are the per-PE partitions
+    host = {"deg": np.asarray(g.out_degrees, np.int32),
+            "init": program.materialize_init(V)}
 
     pes = 1 if exchange_op is None else exchange_op.pes
     # which plane exchanges across PEs (for runtime transfer accounting):
@@ -1871,11 +1982,12 @@ def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
     sub_sweep = None
     if fused.kernel == "edge_block":
         pplan = lay.pull_plan(schedule.pull_block_slots)
+        host["pull"] = _pull_device_arrays(pplan)
         reduce_module, sub_sweep = _emit_dense_pull_reduce(
-            ir, fused, pplan, out_deg, schedule, use_pallas)
+            ir, fused, pplan, schedule, use_pallas)
     else:
-        partial_reduce, chunk_arrays, nchunk = _emit_segment_scan_reduce(
-            ir, fused, lay.reverse_coo(), V, g.num_edges, out_deg, splan)
+        scan_chunks, host["coo"], nchunk = _emit_segment_scan_reduce(
+            ir, fused, lay.reverse_coo(), V, g.num_edges, splan)
         chunk_geometry = (nchunk, splan.chunk_size)
         if exchange_op is not None:
             exchange_quantized = (
@@ -1883,11 +1995,13 @@ def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
                 and exchange_op.collective == "psum"
                 and jnp.issubdtype(dtype, jnp.floating))
             reduce_module = _emit_exchange(
-                exchange_op, partial_reduce, chunk_arrays, nchunk,
-                splan.mesh, quantized=exchange_quantized, num_vertices=V)
+                exchange_op, scan_chunks, splan.mesh,
+                quantized=exchange_quantized, num_vertices=V)
             exchange_plane = "pull"
         else:
-            reduce_module = partial_reduce
+            def reduce_module(layouts, values, active):
+                return scan_chunks(layouts["coo"], layouts["deg"], values,
+                                   active)
 
     # ---- superstep = Receive/Reduce (module) + Apply + frontier ---------
     apply_fn = apply_op.fn
@@ -1896,8 +2010,8 @@ def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
 
     def make_superstep(module):
         @jax.jit
-        def superstep(values, active):
-            red, got = module(values, active)
+        def superstep(layouts, values, active):
+            red, got = module(layouts, values, active)
             new = apply_fn(values, red)
             if frontier_dead:
                 # frontier='all': every vertex stays active, no change mask
@@ -1924,8 +2038,9 @@ def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
     pull_blocks_total = None
     if pull_sweep == "bitmap":
         fe_pull = lay.forward_ell(schedule.push_ell_width)
+        host["fwd"] = _fwd_device_arrays(fe_pull)
         superstep, pull_block_tiers = _emit_pull_bitmap(
-            ir, fstep, pplan, fe_pull, out_deg, reduce_module, sub_sweep,
+            ir, fstep, pplan, fe_pull, reduce_module, sub_sweep,
             use_pallas, g.num_edges, schedule)
         pull_blocks_total = pplan.num_blocks
     else:
@@ -1936,7 +2051,6 @@ def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
     push_superstep = None
     push_layout = None
     push_tiers = None
-    rows_per_vertex = None
     push_rf_fn = None
     push_stat_pes = 1
     push_pe_rows = None
@@ -1945,36 +2059,35 @@ def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
         push_layout = push_op.layout
         if push_op.layout == "fwd_ell":
             fe = lay.forward_ell(schedule.push_ell_width)
-            rows_per_vertex = fe.rows_per_vertex
             if exchange_op is not None and splan.mesh is not None \
                     and fe.num_rows >= 1:
                 # multi-PE: shard the push plane over forward-ELL intervals
                 sfe = lay.forward_ell_shards(schedule.push_ell_width, pes)
+                host["fwd_pe"] = _fwd_pe_device_arrays(sfe)
                 push_superstep, push_tiers, push_rf_fn = \
                     _emit_push_ell_sharded(
-                        ir, push_op, sfe, out_deg, apply_fn, reduce_module,
+                        ir, push_op, sfe, apply_fn, reduce_module,
                         use_pallas, splan.mesh, exchange_op)
                 push_stat_pes = pes
                 push_pe_rows = sfe.rows_per_pe
                 push_pe_edges = sfe.edges_per_pe
                 exchange_plane = "push"
             else:
-                push_superstep, push_tiers = _emit_push_ell(
-                    ir, push_op, fe, out_deg, apply_fn, reduce_module,
-                    use_pallas)
+                host["fwd"] = _fwd_device_arrays(fe)
+                push_superstep, push_tiers, push_rf_fn = _emit_push_ell(
+                    ir, push_op, fe, apply_fn, reduce_module, use_pallas)
         else:
-            push_superstep = make_superstep(
-                _emit_push_scatter(ir, push_op, g, out_deg, splan))
+            scatter, host["push_coo"] = _emit_push_scatter(ir, push_op, g,
+                                                           splan)
+            push_superstep = make_superstep(scatter)
     if fused.kernel == "edge_block" and exchange_plane != "push":
         # a dense plan whose push plane didn't shard (edgeless forward
         # ELL, or no push twin) runs fully replicated — report pes=1
         pes = 1
 
-    # the default init values are program-static: materialize once at
-    # staging time and stage the rooted form (the eager per-run scatter
-    # chain cost ~1 ms of every run() call)
-    base_values = program.materialize_init(V)
-
+    # the default init values are program-static: materialized once at
+    # staging time (layouts["init"]) with the rooted form staged (the
+    # eager per-run scatter chain cost ~1 ms of every run() call)
     @jax.jit
     def _rooted(values, roots):
         root_val = jnp.asarray(0, dtype)
@@ -1982,22 +2095,23 @@ def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
         active = jnp.zeros((V,), bool).at[roots].set(True)
         return values, active
 
-    def init_state(roots=None, values=None):
+    def init_state(layouts, roots=None, values=None):
         if values is None:
-            values = base_values
+            values = layouts["init"]
         if roots is not None:
             return _rooted(values, jnp.asarray(roots))
         return values, jnp.ones((V,), bool)
+
+    layouts = _place_layouts(host, comm, splan.mesh)
 
     emit_s = time.perf_counter() - t_emit0
     preprocess_s = sum(lay.build_times_s.values()) - pre_before
     return {
         "layouts": lay,
+        "device_layouts": layouts,
         "superstep": superstep,
         "push_superstep": push_superstep,
         "init_state": init_state,
-        "out_degrees": out_deg,
-        "rows_per_vertex": rows_per_vertex,
         "push_tiers": push_tiers,
         "push_layout": push_layout,
         "push_rf_fn": push_rf_fn,

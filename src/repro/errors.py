@@ -22,6 +22,7 @@ __all__ = [
     "StreamRetryError",
     "InvalidQuery",
     "QueueFull",
+    "DeviceCountError",
     "DiagnosticError",
     "IRVerificationError",
     "CheckpointError",
@@ -92,6 +93,21 @@ class InvalidQuery(ReproError, ValueError):
     Subclasses ``ValueError`` — the pre-typed serving API raised bare
     ``ValueError`` for these, and existing handlers keep working.
     """
+
+
+class DeviceCountError(ReproError, ValueError):
+    """A schedule asked for more PEs than the device pool holds.
+
+    Raised by :func:`repro.core.scheduler.plan` instead of silently
+    running on fewer devices; :func:`repro.core.scheduler.plan_for_devices`
+    is the explicit path that re-plans onto a smaller pool.  Carries
+    ``requested`` and ``available``.
+    """
+
+    def __init__(self, message: str, *, requested: int, available: int):
+        super().__init__(message)
+        self.requested = requested
+        self.available = available
 
 
 class DiagnosticError(ReproError):

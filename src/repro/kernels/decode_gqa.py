@@ -73,7 +73,7 @@ def decode_gqa(
     *,
     scale: float | None = None,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:            # (B, K, G, h)
     B, S, K, h = k_cache.shape
     G = q.shape[2]

@@ -93,7 +93,7 @@ def edge_block_reduce(
     reduce: str,
     mask_inactive: bool = True,
     block_rows: int = 128,
-    interpret: bool = True,
+    interpret: bool,
     block_live: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Pallas dispatch with padding/unpadding. Returns (reduced, any_live).

@@ -56,9 +56,11 @@ def touched_frontier(row_src, ell_dst, active, *, num_rows, capacity,
     vertices have at least one active in-neighbor.  Returns the
     ``(V+1,)`` uint8 touched table (see ``pull_bitmap.touched_table``);
     callers wanting the packed wire form apply
-    ``repro.core.graph.pack_bits(table[:V] != 0)``."""
+    ``repro.core.graph.pack_bits(table[:V] != 0)``.  ``ell_dst`` is the
+    host :class:`~repro.core.graph.ForwardELL`'s row-major ``(R, W)``
+    array (the kernel takes it slot-major)."""
     return _pull_bitmap.touched_table(
-        row_src, ell_dst, active, num_rows=num_rows, capacity=capacity,
+        row_src, ell_dst.T, active, num_rows=num_rows, capacity=capacity,
         num_vertices=num_vertices)
 
 
@@ -112,15 +114,17 @@ def push_ell_reduce(row_src, ell_dst, ell_wgt, values, degrees, active, *,
     The test/direct-caller convenience over
     ``push_ell.push_ell_reduce``: ``gather`` is a menu-module name, the
     reduce identity is folded here, and ``emit_touched`` defaults on so the
-    result tuple matches ``ref.push_scatter_reduce_ref``.  The translator
-    instead stages the kernel module directly with its own traced callable
-    and capacity tiers.
+    result tuple matches ``ref.push_scatter_reduce_ref``.  ``ell_dst``
+    and ``ell_wgt`` are the host forward ELL's row-major ``(R, W)``
+    arrays (the kernel takes them slot-major).  The translator instead
+    stages the kernel module directly with its own traced callable and
+    capacity tiers.
     """
     if not mask_inactive:
         active = jnp.ones_like(active)
     ident = _ref._identity(reduce, values.dtype)
     return _push_ell.push_ell_reduce(
-        row_src, ell_dst, ell_wgt, values, degrees, active,
+        row_src, ell_dst.T, ell_wgt.T, values, degrees, active,
         num_rows=num_rows, capacity=capacity,
         gather_fn=partial(_ref.gather_msg, gather), reduce=reduce,
         identity=ident, num_vertices=values.shape[0], dtype=values.dtype,

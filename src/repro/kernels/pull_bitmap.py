@@ -51,9 +51,7 @@ import jax.numpy as jnp
 
 from ..core import graph as _G
 from .push_ell import compact_rows
-from .ref import PAD, _identity, gather_msg
-
-_ROW_REDUCE = {"add": jnp.sum, "min": jnp.min, "max": jnp.max}
+from .ref import PAD, ROW_REDUCE, _identity, gather_msg
 
 
 def touched_table(row_src: jax.Array, ell_dst: jax.Array, active: jax.Array,
@@ -62,7 +60,8 @@ def touched_table(row_src: jax.Array, ell_dst: jax.Array, active: jax.Array,
     """Per-superstep any-active summary: which vertices have a live in-edge.
 
     A compacted forward scatter of frontier *bits* (no weights, no message
-    compute): live forward-ELL rows compact into ``capacity`` slots and
+    compute) over the slot-major forward ELL (``ell_dst`` is ``(W, R)``,
+    as in ``push_ell``): live rows compact into ``capacity`` slots and
     their destination ids mark a ``(V+1,)`` uint8 table (slot ``V`` is the
     dummy the padded block maps read — it stays 0 because PAD destinations
     write 0 there).  Correct only while ``capacity`` covers the live row
@@ -73,7 +72,7 @@ def touched_table(row_src: jax.Array, ell_dst: jax.Array, active: jax.Array,
     if num_rows == 0:
         live = jnp.zeros_like(live)
     sel, ok = compact_rows(live, num_rows, capacity)
-    dst_blk = jnp.where(ok[:, None], ell_dst[sel], PAD)
+    dst_blk = jnp.where(ok[None, :], ell_dst[:, sel], PAD)
     valid = dst_blk != PAD
     idx = jnp.where(valid, dst_blk, num_vertices).reshape(-1)
     val = valid.reshape(-1).astype(jnp.uint8)
@@ -141,20 +140,22 @@ def subrow_combine(sub_red: jax.Array, plan, identity, reduce: str,
     """Flat sub-row reductions → (V,) table, scatter-free.
 
     The combine cascade of the flat width-8 view: per bucket a static
-    ``reshape(R_b, W_b/8)`` reduction folds sub-rows back to bucket rows
-    (a vertex's sub-rows are consecutive, in lane order — so the per-row
-    reduction order matches the bucketed sweep's and float ``add`` stays
-    deterministic), then :func:`combine_rows` maps rows to vertices.
+    ``reshape(W_b/8, R_b)`` reduction over the leading axis folds sub-rows
+    back to bucket rows (the view is stripe-major: sub-row ``k`` of row
+    ``r`` sits at ``k·R_b + r``, so the reduced axis is the short one and
+    the long one stays minor — TPU-friendly; the fold runs in sub-row
+    order, so float ``add`` stays deterministic), then
+    :func:`combine_rows` maps rows to vertices.
     ``sub_red`` is the ``(num_subrows,)`` per-sub-row reduction (pad
     sub-rows must hold the identity).
     """
-    rop = _ROW_REDUCE[reduce]
+    rop = ROW_REDUCE[reduce]
     parts = []
     for (rows_b, f_b), off in zip(plan.bucket_shapes,
                                   plan.bucket_sub_offsets):
         seg = sub_red[off:off + rows_b * f_b]
         if f_b > 1:
-            parts.append(rop(seg.reshape(rows_b, f_b), axis=1))
+            parts.append(rop(seg.reshape(f_b, rows_b), axis=0))
         else:
             parts.append(seg)
     parts.append(jnp.full((1,), identity, dtype))

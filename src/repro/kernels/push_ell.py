@@ -24,6 +24,13 @@ instead of a dense sweep guarded per chunk.  Three stages:
    (~300 ns/edge) dwarfs the unsorted segment reduce (~90 ns/edge), so the
    sort is reserved for backends where it pays.
 
+The forward ELL arrives **slot-major** — ``ell_dst``/``ell_wgt`` are
+``(width, R)`` — and every stage works on ``(width, capacity)`` blocks:
+on a TPU a minor dimension of ``width`` would be tiled to 128 lanes,
+padding each row-major ``(capacity, width)`` intermediate 16× (the
+``ops.push_ell_reduce`` wrapper takes the host ``ForwardELL``'s
+row-major arrays and transposes them).
+
 Work is O(R + capacity·width) per superstep instead of O(E): the runtime
 direction policy picks a capacity tier that covers the live row count
 ``r_f`` (or falls back to the dense engine when the frontier is too wide
@@ -77,13 +84,14 @@ def compact_rows(live: jax.Array, num_rows: int, capacity: int
 
 
 def _messages_xla(dst_blk, wgt_blk, src_blk, values, degrees, *, gather_fn):
-    """Reference message stage: gather vertex state, apply the gather fn."""
+    """Reference message stage over a slot-major ``(W, C)`` block: gather
+    vertex state, apply the gather fn."""
     v = values[src_blk]                                  # (C,)
     d = degrees[src_blk]
     shape = dst_blk.shape
-    return gather_fn(jnp.broadcast_to(v[:, None], shape),
+    return gather_fn(jnp.broadcast_to(v[None, :], shape),
                      jnp.broadcast_to(wgt_blk, shape).astype(v.dtype),
-                     jnp.broadcast_to(d[:, None], shape))
+                     jnp.broadcast_to(d[None, :], shape))
 
 
 def _message_kernel(dst_ref, wgt_ref, src_ref, val_ref, deg_ref, out_ref,
@@ -115,7 +123,7 @@ def _message_kernel(dst_ref, wgt_ref, src_ref, val_ref, deg_ref, out_ref,
 
 def push_ell_message_block(dst_blk, wgt_blk, src_blk, values, degrees, *,
                            gather: str, block_rows: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """Pallas dispatch for the message stage over compacted edge blocks."""
     assert gather in GATHER_OPS
     c, w = dst_blk.shape
@@ -150,8 +158,8 @@ def push_ell_message_block(dst_blk, wgt_blk, src_blk, values, degrees, *,
 
 def push_ell_reduce(
     row_src: jax.Array,     # (max(R,1),) int32 owner vertex per ELL row
-    ell_dst: jax.Array,     # (max(R,1), W) int32 destinations, PAD-padded
-    ell_wgt: jax.Array,     # (max(R,1), W) edge weights
+    ell_dst: jax.Array,     # (W, max(R,1)) int32 destinations, PAD-padded
+    ell_wgt: jax.Array,     # (W, max(R,1)) edge weights
     values: jax.Array,      # (V,) vertex values
     degrees: jax.Array,     # (V,) out-degrees (gather's third argument)
     active: jax.Array,      # (V,) bool frontier
@@ -165,7 +173,7 @@ def push_ell_reduce(
     dtype,
     gather_module: str | None = None,   # menu name -> Pallas-eligible
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool,
     emit_touched: bool = False,
 ) -> tuple[jax.Array, jax.Array | None]:
     """Frontier-compacted push superstep reduce.  Returns ``(red, touched)``.
@@ -195,8 +203,8 @@ def push_ell_reduce(
 
 def compacted_push_reduce(
     row_src: jax.Array,     # (R_storage,) int32 owner vertex per row
-    ell_dst: jax.Array,     # (R_storage, W) int32 destinations, PAD-padded
-    ell_wgt: jax.Array,     # (R_storage, W) edge weights
+    ell_dst: jax.Array,     # (W, R_storage) int32 destinations, PAD-padded
+    ell_wgt: jax.Array,     # (W, R_storage) edge weights
     live: jax.Array,        # (R_storage,) bool: row is live this superstep
     values: jax.Array,      # (V,) vertex values
     degrees: jax.Array,     # (V,) out-degrees (gather's third argument)
@@ -210,7 +218,7 @@ def compacted_push_reduce(
     dtype,
     gather_module: str | None = None,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool,
     emit_touched: bool = False,
 ) -> tuple[jax.Array, jax.Array | None]:
     """Compaction → gather/message → segment-combine over a live-row mask.
@@ -227,13 +235,13 @@ def compacted_push_reduce(
         raise ValueError(reduce)
     identity = jnp.asarray(identity, dtype)
     sel, ok = compact_rows(live, num_rows, capacity)
-    dst_blk = jnp.where(ok[:, None], ell_dst[sel], PAD)   # (cap, W)
-    wgt_blk = ell_wgt[sel]
+    dst_blk = jnp.where(ok[None, :], ell_dst[:, sel], PAD)   # (W, cap)
+    wgt_blk = ell_wgt[:, sel]
     src_blk = row_src[sel]
     if use_pallas and gather_module is not None:
-        msg = push_ell_message_block(dst_blk, wgt_blk, src_blk, values,
+        msg = push_ell_message_block(dst_blk.T, wgt_blk.T, src_blk, values,
                                      degrees, gather=gather_module,
-                                     interpret=interpret)
+                                     interpret=interpret).T
     else:
         msg = _messages_xla(dst_blk, wgt_blk, src_blk, values, degrees,
                             gather_fn=gather_fn)

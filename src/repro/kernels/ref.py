@@ -11,6 +11,9 @@ PAD = jnp.iinfo(jnp.int32).max
 GATHER_OPS = ("copy", "plus_one", "add_w", "mul_w", "div_deg")
 REDUCE_OPS = ("add", "min", "max")
 
+# Reduce op → the jnp reduction along one axis (row/slot reductions).
+ROW_REDUCE = {"add": jnp.sum, "min": jnp.min, "max": jnp.max}
+
 # Menu gathers whose message ignores the edge weight: their per-edge
 # message depends only on the source vertex, so the dense pull sweep can
 # precompute one (V,)-table of masked messages and stream edges as a

@@ -54,7 +54,7 @@ def segment_reduce(
     *,
     reduce: str = "add",
     block_e: int = 4096,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     E = seg.shape[0]
     epad = (-E) % block_e

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 
 from ..configs.base import ArchConfig, ShapeConfig, SHAPES, cell_is_runnable
 from ..configs.registry import get_config, input_specs
-from ..core._jax_compat import set_mesh
 from ..models.model import LModel
 from ..models.param import abstract
 from ..sharding import partition as ps
@@ -187,5 +186,5 @@ def build_cell(arch: str, shape_name: str, mesh, *,
 
 
 def lower_cell(cell: Cell, mesh):
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(cell.fn, donate_argnums=cell.donate).lower(*cell.args)

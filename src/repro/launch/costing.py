@@ -28,7 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ArchConfig, ShapeConfig, SHAPES
-from ..core._jax_compat import set_mesh
 from ..configs.registry import get_config, input_specs
 from ..models.model import LModel
 from ..models.param import abstract
@@ -100,7 +99,7 @@ def _fd_cfg(cfg: ArchConfig, n_cycles: int) -> ArchConfig:
 
 def _measure(fn, args, mesh, donate=()) -> tuple[dict, float, dict]:
     t0 = time.perf_counter()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     dt = time.perf_counter() - t0
     ca = compiled.cost_analysis() or {}

@@ -29,7 +29,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..core._jax_compat import get_abstract_mesh, pvary, shard_map
 import numpy as np
 
 from ..configs.base import ArchConfig
@@ -233,8 +232,8 @@ def _moe_local(cfg: ArchConfig, p, x, n_model: int):
     me_l = jnp.mean(probs, axis=(0, 1))
     fe_l = jnp.mean(jax.nn.one_hot(idx[..., 0], E, dtype=jnp.float32),
                     axis=(0, 1))
-    me = jax.lax.pmean(pvary(me_l, ("model",)), ("data", "model"))
-    fe = jax.lax.pmean(pvary(fe_l, ("model",)), ("data", "model"))
+    me = jax.lax.pmean(jax.lax.pcast(me_l, ("model",), to="varying"), ("data", "model"))
+    fe = jax.lax.pmean(jax.lax.pcast(fe_l, ("model",), to="varying"), ("data", "model"))
     aux = cfg.moe_aux_coef * E * jnp.sum(fe * me)
 
     # keep only this shard's experts: remap to local ids, route everything
@@ -250,10 +249,10 @@ def _moe_local(cfg: ArchConfig, p, x, n_model: int):
     keep_tok = keep_tok & (e_tok < e_loc)
     e_tok = jnp.where(e_tok < e_loc, e_tok, 0)
 
-    # pvary: x is model-invariant but the dispatch result is model-varying;
+    # pcast: x is model-invariant but the dispatch result is model-varying;
     # marking it explicitly makes the custom-VJP cotangent types line up and
     # its transpose (psum over 'model') is exactly the right math
-    xv = pvary(x, ("model",))
+    xv = jax.lax.pcast(x, ("model",), to="varying")
     buf = dispatch(xv, slot_tok, e_tok, rank_tok, keep_tok)    # (G,e_loc,C,D)
 
     act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
@@ -269,7 +268,7 @@ def _moe_local(cfg: ArchConfig, p, x, n_model: int):
 
 def apply_moe_shardmap(cfg: ArchConfig, p, x):
     from jax.sharding import PartitionSpec as P
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     axes = dict(mesh.shape)
     n_model = axes.get("model", 1)
     batch_axes = tuple(a for a in ("pod", "data") if a in axes)
@@ -281,7 +280,7 @@ def apply_moe_shardmap(cfg: ArchConfig, p, x):
         "w_gate": P("model", None, None),
         "w_down": P("model", None, None),
     }
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda p_, x_: _moe_local(cfg, p_, x_, n_model),
         mesh=mesh,
         in_specs=(p_specs, P(bspec, None, None)),
@@ -294,7 +293,7 @@ def apply_moe_auto(cfg: ArchConfig, p, x):
     """Module selection (the paper's translator idea): pick the EP-psum
     shard_map implementation when the mesh allows it, else the gather one."""
     if cfg.moe_impl == "shardmap":
-        mesh = get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if mesh is not None and mesh.axis_names:
             n_model = dict(mesh.shape).get("model", 1)
             if (n_model > 1 and cfg.n_experts % n_model == 0

@@ -210,7 +210,7 @@ def choose_landmarks(g: G.Graph, k: int) -> np.ndarray:
 
 def build_landmark_table(g: G.Graph, k: int, *,
                          schedule: ScheduleConfig | None = None,
-                         use_pallas: bool | None = None) -> LandmarkTable:
+                         use_pallas: bool = False) -> LandmarkTable:
     """SSSP from k landmarks on g and its transpose, batched per sweep.
 
     Both sweeps run through the same translate/run_batch plane the server
@@ -440,7 +440,7 @@ class GraphServer:
                  admission: AdmissionPolicy | None = None,
                  landmarks: int = 0,
                  comm: CommManager | None = None,
-                 use_pallas: bool | None = None,
+                 use_pallas: bool = False,
                  ppr_damping: float = 0.85, ppr_iters: int = 20):
         self.graph = g
         self.schedule = schedule or ScheduleConfig()

@@ -20,7 +20,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core._jax_compat import get_abstract_mesh
 
 # A rule value is a tuple of candidates; each candidate is a mesh-axis name
 # or a tuple of mesh-axis names (joint sharding of one dim).
@@ -165,7 +164,7 @@ def named_shardings(specs: Any, mesh: Mesh) -> Any:
 
 
 def _ambient_axes() -> dict[str, int]:
-    m = get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or not m.axis_names:
         return {}
     return dict(m.shape)

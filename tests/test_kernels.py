@@ -1,4 +1,9 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret=True)."""
+import os
+import pathlib
+import subprocess
+import sys
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -122,3 +127,18 @@ def test_decode_gqa_bf16():
     b = decode_gqa_ref(q, kc, vc, pos, length)
     np.testing.assert_allclose(np.asarray(a, np.float32),
                                np.asarray(b, np.float32), atol=3e-2)
+
+
+def test_kernels_package_imports_in_a_fresh_interpreter():
+    """``import repro.kernels`` first (before ``repro.core``) must not hit
+    the kernels ↔ translator import cycle."""
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [str(src), os.environ.get("PYTHONPATH", "")]))
+    for module in ("repro.kernels", "repro.kernels.pull_bitmap",
+                   "repro.kernels.push_ell"):
+        proc = subprocess.run([sys.executable, "-c", f"import {module}"],
+                              env=env, capture_output=True, text=True,
+                              timeout=120)
+        assert proc.returncode == 0, proc.stderr[-2000:]

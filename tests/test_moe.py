@@ -90,9 +90,9 @@ cfg = dataclasses.replace(smoke_config("moonshot-v1-16b-a3b"),
                           dtype="float32", capacity_factor=8.0)
 p = materialize(M.moe_specs(cfg), jax.random.key(0), dtype=jnp.float32)
 x = jax.random.normal(jax.random.key(1), (4, 16, cfg.d_model), jnp.float32)
-from repro.core._jax_compat import make_mesh, set_mesh
+from repro.core._jax_compat import make_mesh
 mesh = make_mesh((2, 4), ("data", "model"))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     def f(fn):
         def loss(p, x):
             y, aux = fn(cfg, p, x)

@@ -18,6 +18,7 @@ from repro.core.comm import CommManager
 from repro.core.scheduler import (DirectionPolicy, ScheduleConfig, plan,
                                   plan_for_devices)
 from repro.core.translator import translate
+from repro.errors import DeviceCountError
 
 needs2 = pytest.mark.skipif(len(jax.devices()) < 2,
                             reason="needs >= 2 devices")
@@ -203,16 +204,22 @@ def test_edgeless_graph_end_to_end(pes):
 @needs2
 def test_elastic_degrade_replan_multi_pe(g):
     """plan_for_devices clamps to the surviving pool; an over-asked
-    ScheduleConfig degrades to the available devices and still matches."""
+    ScheduleConfig is refused by translate, and the re-planned schedule
+    runs on the pool and still matches."""
     cfg = ScheduleConfig(pes=8)
     p = plan_for_devices(cfg, num_devices=3, num_vertices=g.num_vertices,
                          num_edges=g.num_edges)
     assert p.pes == min(3, len(jax.devices()))
     assert p.num_chunks % p.pes == 0
-    # asking for more PEs than devices exist: translate degrades, runs,
-    # and stays bit-exact
+    # asking for more PEs than devices exist: translate refuses with the
+    # typed error; the explicit re-plan onto the pool runs bit-exact
+    with pytest.raises(DeviceCountError):
+        translate(dsl.bfs_program(alg.INT_MAX), g, _cfg(16))
     base, _ = translate(dsl.bfs_program(alg.INT_MAX), g, _cfg(1)).run(roots=0)
-    prog = translate(dsl.bfs_program(alg.INT_MAX), g, _cfg(16))
+    replanned = plan_for_devices(_cfg(16), num_devices=16,
+                                 num_vertices=g.num_vertices,
+                                 num_edges=g.num_edges)
+    prog = translate(dsl.bfs_program(alg.INT_MAX), g, replanned.config)
     vals, _ = prog.run(roots=0)
     assert prog.report.pes == len(jax.devices())
     np.testing.assert_array_equal(np.asarray(base), np.asarray(vals))
@@ -288,7 +295,8 @@ def test_quantized_psum_unit_bound():
 @needs2
 def test_run_batch_multi_pe_matches_sequential(g):
     """vmapped batched runs work over the sharded push engine too
-    (shard_map_unchecked sidesteps 0.4.x's vmap replication-check bug)."""
+    (shard_map_unchecked: the vma checker cannot type a vmapped
+    shard_map)."""
     prog = translate(dsl.bfs_program(alg.INT_MAX), g, _cfg(2))
     roots = [0, 7, 31]
     bv, bi = prog.run_batch(roots)

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.core.comm import CommManager
+from repro.errors import DeviceCountError
 from repro.core.scheduler import (DirectionPolicy, ScheduleConfig,
                                   choose_backend, plan, plan_for_devices)
 
@@ -50,11 +51,18 @@ def test_plan_explicit_backend_override_beats_heuristic():
 
 
 def test_plan_elastic_degrade_to_single_device():
-    """pes > available devices degrades instead of failing."""
+    """pes > available devices raises a typed error instead of quietly
+    running on fewer PEs; plan_for_devices is the explicit degrade."""
     import jax
-    p = plan(ScheduleConfig(pes=8), num_vertices=10, num_edges=50,
-             devices=jax.devices()[:1])       # simulate a 1-device pool
-    assert p.mesh is None          # degraded: single device → no mesh
+    one = jax.devices()[:1]                   # simulate a 1-device pool
+    with pytest.raises(DeviceCountError) as err:
+        plan(ScheduleConfig(pes=8), num_vertices=10, num_edges=50,
+             devices=one)
+    assert err.value.requested == 8 and err.value.available == 1
+    assert isinstance(err.value, ValueError)
+    p = plan_for_devices(ScheduleConfig(pes=8), num_devices=1,
+                         num_vertices=10, num_edges=50)
+    assert p.mesh is None          # explicit degrade: single device
     assert p.pes == 1
     assert f"direction={p.direction.describe()}" in p.describe()
     assert p.describe().endswith(f"pull_sweep={p.config.pull_sweep}")
@@ -150,7 +158,7 @@ def test_bitmap_or_equals_pmax_of_unpacked():
         pytest.skip("needs >= 2 devices")
     import jax.numpy as jnp
     from repro.core import graph as G
-    from repro.core._jax_compat import make_mesh, shard_map
+    from repro.core._jax_compat import make_mesh
     from jax.sharding import PartitionSpec as P
     rng = np.random.default_rng(5)
     V = 101                                          # not a multiple of 32
@@ -164,8 +172,8 @@ def test_bitmap_or_equals_pmax_of_unpacked():
         ref = jax.lax.pmax(m.astype(jnp.int8), "pe") != 0
         return packed[None], ref[None]
 
-    packed, ref = shard_map(pe_body, mesh=mesh, in_specs=(P("pe"),),
-                            out_specs=(P("pe"), P("pe")))(
+    packed, ref = jax.shard_map(pe_body, mesh=mesh, in_specs=(P("pe"),),
+                                out_specs=(P("pe"), P("pe")))(
         jnp.asarray(masks))
     np.testing.assert_array_equal(np.asarray(packed), np.asarray(ref))
     np.testing.assert_array_equal(np.asarray(packed[0]),

@@ -84,7 +84,7 @@ import jax, jax.numpy as jnp
 from repro.configs import smoke_config
 from repro.launch import cells as C
 import dataclasses
-from repro.core._jax_compat import make_mesh, set_mesh
+from repro.core._jax_compat import make_mesh
 mesh = make_mesh((2, 4), ("data", "model"))
 import repro.configs.base as B
 cfg = dataclasses.replace(smoke_config("qwen3-8b"),
@@ -93,7 +93,7 @@ shape = B.ShapeConfig("t", 32, 8, "train")
 import repro.configs.registry as R
 R_SHAPES = dict(B.SHAPES); B.SHAPES["t"] = shape
 cell = C.build_cell("qwen3-8b", "t", mesh, cfg_override=cfg)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     comp = jax.jit(cell.fn, donate_argnums=cell.donate).lower(*cell.args).compile()
 m = comp.memory_analysis()
 assert m.temp_size_in_bytes < 1 << 30

@@ -136,3 +136,59 @@ def test_superstep_idempotent_when_converged(g):
     v2, active = prog.superstep(values, jnp.zeros(g.num_vertices, bool))
     np.testing.assert_array_equal(np.asarray(values), np.asarray(v2))
     assert not bool(np.asarray(active).any())
+
+
+def _nested_consts(jaxpr) -> list:
+    """Every constant closed over anywhere in a jaxpr, sub-jaxprs included."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    out = []
+
+    def visit(x):
+        if isinstance(x, ClosedJaxpr):
+            out.extend(x.consts)
+            visit(x.jaxpr)
+        elif isinstance(x, Jaxpr):
+            for eqn in x.eqns:
+                for p in eqn.params.values():
+                    for q in (p if isinstance(p, (tuple, list)) else (p,)):
+                        visit(q)
+    visit(jaxpr)
+    return out
+
+
+@pytest.mark.parametrize("case", ["bfs-auto", "bfs-bitmap", "sssp-sparse",
+                                  "pagerank", "bfs-pes2", "sssp-sparse-pes2"])
+def test_staged_programs_take_layouts_as_arguments(g, case):
+    """No staged function closes over a graph layout: the run loop, the
+    serving plane's slice/admit/init programs all see the graph only
+    through their ``layouts`` argument (a closed-over array would lower
+    to an HLO constant the size of the graph)."""
+    import jax
+    from repro.core import algorithms as alg
+    from repro.core.scheduler import DirectionPolicy
+    if case.endswith("pes2") and len(jax.devices()) < 2:
+        pytest.skip("needs >= 2 devices")
+    program = {"bfs": dsl.bfs_program(alg.INT_MAX),
+               "sssp": dsl.sssp_program(),
+               "pagerank": dsl.pagerank_program()}[case.split("-")[0]]
+    cfg = ScheduleConfig(
+        direction=DirectionPolicy(mode="auto"),
+        pull_sweep="bitmap" if "bitmap" in case else "auto",
+        backend="sparse" if "sparse" in case else "auto",
+        pes=2 if case.endswith("pes2") else 1)
+    prog = translate(program, g, cfg, aot_compile=False)
+    layout_leaves = jax.tree.leaves(prog.layouts)
+    assert min(a.size for a in layout_leaves) >= 1
+    rooted = program.frontier == "changed"
+    v, a = prog.init_state(roots=0 if rooted else None)
+    state = prog.batch_idle(2)
+    staged = [jax.make_jaxpr(prog.run_loop)(prog.layouts, v, a),
+              jax.make_jaxpr(prog._slice_fn())(prog.layouts, state,
+                                               jnp.int32(3))]
+    for j in staged:
+        assert all(np.size(c) <= 1 for c in _nested_consts(j))
+    if cfg.pes > 1:
+        sharded = prog.layouts["fwd_pe" if prog.report.exchange_plane
+                               == "push" else "coo"]
+        leaf = jax.tree.leaves(sharded)[0]
+        assert len(leaf.sharding.device_set) == 2
