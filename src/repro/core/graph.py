@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..errors import GraphValidationError
+from . import tracing
 
 # Sentinel for padded edge slots (points at a dummy vertex appended at n).
 PAD = jnp.iinfo(jnp.int32).max
@@ -487,7 +488,23 @@ def from_edge_list(
     ``validate=True`` additionally rejects out-of-range sources up front
     and runs :func:`validate_graph` on the result — an out-of-range ``src``
     would otherwise crash obscurely inside the bincount/cumsum below.
+    Runs in the host span ``repro.ingest``: ``repro.ingest.csr`` (the host
+    CSR build) then ``repro.ingest.place`` (the copy to the device).
     """
+    with tracing.span("ingest"):
+        with tracing.span("ingest.csr"):
+            csr = _csr(src, dst, num_vertices, weights, vertex_values, sort,
+                       validate)
+        with tracing.span("ingest.place"):
+            g = _place_csr(*csr)
+        if validate:
+            validate_graph(g)
+    return g
+
+
+def _csr(src, dst, num_vertices, weights, vertex_values, sort, validate):
+    """Host CSR arrays of a COO edge list: ``(vertex_values, offsets, dst,
+    weights, num_vertices)``."""
     src = np.asarray(src, np.int32)
     dst = np.asarray(dst, np.int32)
     if num_vertices is None:
@@ -498,9 +515,8 @@ def from_edge_list(
         raise GraphValidationError(
             f"src[{bad}] = {int(src[bad])} out of range "
             f"[0, {num_vertices})")
-    e = len(src)
     if weights is None:
-        weights = np.ones(e, np.float32)
+        weights = np.ones(len(src), np.float32)
     if sort:
         order = np.argsort(src, kind="stable")
         src, dst, weights = src[order], dst[order], weights[order]
@@ -509,17 +525,18 @@ def from_edge_list(
     np.cumsum(counts, out=offsets[1:])
     if vertex_values is None:
         vertex_values = np.zeros(num_vertices, np.float32)
-    g = Graph(
+    return vertex_values, offsets, dst, weights, num_vertices
+
+
+def _place_csr(vertex_values, offsets, dst, weights, num_vertices) -> Graph:
+    return Graph(
         vertex_values=jnp.asarray(vertex_values),
         edge_offsets=jnp.asarray(offsets, jnp.int32),
         edges_dst=jnp.asarray(dst, jnp.int32),
         edge_weights=jnp.asarray(weights),
         num_vertices=num_vertices,
-        num_edges=e,
+        num_edges=len(dst),
     )
-    if validate:
-        validate_graph(g)
-    return g
 
 
 def to_coo(g: Graph) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -533,10 +550,9 @@ def to_coo(g: Graph) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
 def reverse(g: Graph) -> Graph:
     """Transpose the graph (CSR ↔ CSC): out-edges become in-edges."""
     src, dst, w = to_coo(g)
-    return from_edge_list(
-        dst, src, num_vertices=g.num_vertices, weights=w,
-        vertex_values=np.asarray(g.vertex_values),
-    )
+    # not from_edge_list: a layout build, not an ingest (no ingest spans)
+    return _place_csr(*_csr(dst, src, g.num_vertices, w,
+                            np.asarray(g.vertex_values), True, False))
 
 
 def bucketize(

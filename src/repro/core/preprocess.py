@@ -15,6 +15,7 @@ from typing import Literal
 import numpy as np
 
 from . import graph as G
+from . import tracing
 
 # ---------------------------------------------------------------------------
 # 1) FIFO — file I/O (paper: read input files / write outputs / Neo4j hook)
@@ -83,9 +84,10 @@ class GraphLayouts:
     CSR (pull's in-edge view), its degree-bucketed ELL (dense pull), the
     reverse COO (sparse pull), and the forward ELL (compacted push) — each
     built once on first request and reused by every subsequent
-    ``translate()`` on the same graph.  ``build_times_s`` records the
-    seconds spent constructing each product (what the
-    ``TranslationReport.translate_breakdown['preprocess']`` entry sums).
+    ``translate()`` on the same graph.  Each build runs in the host span
+    ``repro.layout.<product>``; ``build_times_s`` records its self seconds
+    per product and key (what the
+    ``TranslationReport.translate_breakdown['preprocess_s']`` entry sums).
     """
 
     graph: G.Graph                      # strong ref: keeps the id-key valid
@@ -97,14 +99,12 @@ class GraphLayouts:
     _forward_ell_shards: dict = dataclasses.field(default_factory=dict)
     _pull_plan: dict = dataclasses.field(default_factory=dict)
 
-    def _timed(self, name: str, build):
+    def _timed(self, product: str, build, key: str | None = None):
         # record *self* time: a nested build (reverse_bucketed → reverse)
-        # books its own entry, so subtract child time or sums double-count
-        t0 = time.perf_counter()
-        children_before = sum(self.build_times_s.values())
-        out = build()
-        child_s = sum(self.build_times_s.values()) - children_before
-        self.build_times_s[name] = time.perf_counter() - t0 - child_s
+        # books its own entry, so its seconds are not counted twice
+        with tracing.span("layout." + product) as sp:
+            out = build()
+        self.build_times_s[key or product] = sp.self_s
         return out
 
     def reverse(self) -> G.Graph:
@@ -138,16 +138,17 @@ class GraphLayouts:
         if block_slots not in self._pull_plan:
             rb = self.reverse_bucketed()
             self._pull_plan[block_slots] = self._timed(
-                f"pull_plan_s{block_slots}",
-                lambda: G.pull_bitmap_plan(rb, block_slots=block_slots))
+                "pull_plan",
+                lambda: G.pull_bitmap_plan(rb, block_slots=block_slots),
+                key=f"pull_plan_s{block_slots}")
         return self._pull_plan[block_slots]
 
     def forward_ell(self, width: int = 8) -> G.ForwardELL:
         """Fixed-width forward ELL (the compacted push engine's layout)."""
         if width not in self._forward_ell:
             self._forward_ell[width] = self._timed(
-                f"forward_ell_w{width}",
-                lambda: G.forward_ell(self.graph, width=width))
+                "forward_ell", lambda: G.forward_ell(self.graph, width=width),
+                key=f"forward_ell_w{width}")
         return self._forward_ell[width]
 
     def forward_ell_shards(self, width: int, pes: int) -> G.ShardedForwardELL:
@@ -162,8 +163,8 @@ class GraphLayouts:
         if key not in self._forward_ell_shards:
             fe = self.forward_ell(width)
             self._forward_ell_shards[key] = self._timed(
-                f"forward_ell_shards_w{width}_p{pes}",
-                lambda: G.shard_forward_ell(fe, pes))
+                "forward_ell_shards", lambda: G.shard_forward_ell(fe, pes),
+                key=f"forward_ell_shards_w{width}_p{pes}")
         return self._forward_ell_shards[key]
 
     def nbytes(self) -> int:

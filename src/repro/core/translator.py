@@ -69,7 +69,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-import time
 import typing
 import jax
 import jax.numpy as jnp
@@ -85,7 +84,7 @@ from ..errors import (CheckpointCorruptError, CheckpointMismatchError,
 from . import checkpoint as ckpt
 from . import faults
 from . import graph as G
-from . import preprocess
+from . import preprocess, tracing
 from ._jax_compat import shard_map_unchecked
 from .analysis import analyze_program
 from .comm import CommManager
@@ -421,18 +420,25 @@ class CompiledGraphProgram:
 
         zero_pstats = tuple(jnp.asarray(0, jnp.int32) for _ in range(3))
 
+        def pull_step(layouts, values, active):
+            with tracing.stage("pull"):
+                return pull(layouts, values, active)
+
+        def push_step(layouts, values, active):
+            with tracing.stage("push"):
+                return (*push(layouts, values, active), zero_pstats)
+
         def step(layouts, direction, values, active):
             # every branch returns (values, active, (swept_edges,
             # blocks_swept, blocks_skipped)) — push supersteps have no
             # pull-plane sweep, so their stats are zeros
             if mode == "pull":
-                return pull(layouts, values, active)
+                return pull_step(layouts, values, active)
             if mode == "push":
-                return (*push(layouts, values, active), zero_pstats)
+                return push_step(layouts, values, active)
             return jax.lax.cond(
-                direction == 1,
-                lambda v, a: (*push(layouts, v, a), zero_pstats),
-                lambda v, a: pull(layouts, v, a), values, active)
+                direction == 1, functools.partial(push_step, layouts),
+                functools.partial(pull_step, layouts), values, active)
 
         def cond(state):
             _, active, it, *_ = state
@@ -442,47 +448,51 @@ class CompiledGraphProgram:
             values, active, it, direction, pushes, compact, switches, \
                 pe_hi, pe_lo, pe_rows, pl_hi, pl_lo, bl_swept, bl_skip, \
                 pull_cost = state
-            alive = jnp.logical_and(jnp.any(active), it < max_iters)
-            new_dir, m_f = choose(layouts, direction, active, pull_cost)
-            rf = live_rows(layouts, active)
+            with tracing.stage("loop.direction"):
+                new_dir, m_f = choose(layouts, direction, active, pull_cost)
+                rf = live_rows(layouts, active)
             new_values, new_active, pstats = step(layouts, new_dir, values,
                                                   active)
-            if probe and jnp.issubdtype(new_values.dtype, jnp.floating):
-                # divergence probe: a NaN anywhere in the new table
-                # freezes the frontier, so the loop exits on the next
-                # cond and run_stats reads terminated='diverged'.  The
-                # poisoned values are kept — they are the evidence.
-                new_active = jnp.logical_and(
-                    new_active, ~jnp.any(jnp.isnan(new_values)))
-            inc = alive.astype(jnp.int32)
-            values = jnp.where(alive, new_values, values)
-            pushes = pushes + new_dir * inc
-            compact = compact + compacted(new_dir, rf) * inc
-            pe_rows = pe_rows + rf * new_dir * inc
-            active = jnp.where(alive, new_active, active)
-            switches = switches + (new_dir != direction).astype(jnp.int32) * inc
-            # push and pull cost counters are both device-side now: m_f
-            # per push superstep, *swept* edges per pull superstep (the
-            # bitmap plane's real cost — E only on dense full sweeps).
-            # Both fit int32 (≤ E) but their sums may not, so accumulate
-            # split 16-bit words (exact up to ~32k supersteps × full
-            # sweeps ≈ 2^47 edges); run() recombines with python ints.
-            m_f = m_f.astype(jnp.int32)
-            pe_hi = pe_hi + (m_f >> 16) * new_dir * inc
-            pe_lo = pe_lo + (m_f & 0xFFFF) * new_dir * inc
-            swept_e, swept_b, skip_b = pstats
-            pull_inc = (1 - new_dir) * inc
-            pl_hi = pl_hi + (swept_e >> 16) * pull_inc
-            pl_lo = pl_lo + (swept_e & 0xFFFF) * pull_inc
-            bl_swept = bl_swept + swept_b * pull_inc
-            bl_skip = bl_skip + skip_b * pull_inc
-            # measured pull-cost register for the m_f-aware direction
-            # choice: what the pull plane actually swept last time
-            pull_cost = jnp.where(pull_inc == 1, swept_e, pull_cost)
-            direction = jnp.where(alive, new_dir, direction)
-            return values, active, it + inc, direction, pushes, compact, \
-                switches, pe_hi, pe_lo, pe_rows, pl_hi, pl_lo, bl_swept, \
-                bl_skip, pull_cost
+            # the superstep's counters, and the freeze of a finished lane
+            with tracing.stage("loop.stats"):
+                alive = jnp.logical_and(jnp.any(active), it < max_iters)
+                if probe and jnp.issubdtype(new_values.dtype, jnp.floating):
+                    # divergence probe: a NaN anywhere in the new table
+                    # freezes the frontier, so the loop exits on the next
+                    # cond and run_stats reads terminated='diverged'.  The
+                    # poisoned values are kept — they are the evidence.
+                    new_active = jnp.logical_and(
+                        new_active, ~jnp.any(jnp.isnan(new_values)))
+                inc = alive.astype(jnp.int32)
+                values = jnp.where(alive, new_values, values)
+                pushes = pushes + new_dir * inc
+                compact = compact + compacted(new_dir, rf) * inc
+                pe_rows = pe_rows + rf * new_dir * inc
+                active = jnp.where(alive, new_active, active)
+                switches = switches \
+                    + (new_dir != direction).astype(jnp.int32) * inc
+                # push and pull cost counters are both device-side now: m_f
+                # per push superstep, *swept* edges per pull superstep (the
+                # bitmap plane's real cost — E only on dense full sweeps).
+                # Both fit int32 (≤ E) but their sums may not, so accumulate
+                # split 16-bit words (exact up to ~32k supersteps × full
+                # sweeps ≈ 2^47 edges); run() recombines with python ints.
+                m_f = m_f.astype(jnp.int32)
+                pe_hi = pe_hi + (m_f >> 16) * new_dir * inc
+                pe_lo = pe_lo + (m_f & 0xFFFF) * new_dir * inc
+                swept_e, swept_b, skip_b = pstats
+                pull_inc = (1 - new_dir) * inc
+                pl_hi = pl_hi + (swept_e >> 16) * pull_inc
+                pl_lo = pl_lo + (swept_e & 0xFFFF) * pull_inc
+                bl_swept = bl_swept + swept_b * pull_inc
+                bl_skip = bl_skip + skip_b * pull_inc
+                # measured pull-cost register for the m_f-aware direction
+                # choice: what the pull plane actually swept last time
+                pull_cost = jnp.where(pull_inc == 1, swept_e, pull_cost)
+                direction = jnp.where(alive, new_dir, direction)
+                return values, active, it + inc, direction, pushes, compact, \
+                    switches, pe_hi, pe_lo, pe_rows, pl_hi, pl_lo, bl_swept, \
+                    bl_skip, pull_cost
 
         return cond, body
 
@@ -572,30 +582,36 @@ class CompiledGraphProgram:
         continues bit-exactly (one shared loop body means slices replay
         the identical superstep sequence).  ``run_stats`` gains
         ``checkpoint_saves``/``checkpoint_loads``/``checkpoint_write_s``.
+
+        A run is the host span ``repro.run``: ``repro.run.dispatch``
+        (``init_state`` and the loop's enqueue), then ``repro.run.wait``
+        (the counters' copy to the host, which waits for the loop to end).
         """
-        if checkpoint_dir is None:
-            checkpoint_dir = self._checkpoint_dir
-        if checkpoint_dir is not None:
-            return self._run_checkpointed(roots, values, checkpoint_dir,
-                                          checkpoint_every, resume)
-        values, active = self.init_state(roots=roots, values=values)
-        values, iters, stats_dev = self.run_loop(self._layouts, values,
-                                                 active)
-        # one host transfer for the whole counter tuple (a per-scalar
-        # int() would pay a device sync each)
-        iters, (pushes, compact, switches, pe_hi, pe_lo, pe_rows,
-                pl_hi, pl_lo, bl_swept, bl_skip, pull_cost, live,
-                nanfree) = \
-            jax.device_get((iters, stats_dev))
-        stats = self._scalar_stats(iters, pushes, compact, switches, pe_hi,
-                                   pe_lo, pe_rows, pl_hi, pl_lo, bl_swept,
-                                   bl_skip, pull_cost, live, nanfree)
-        if self._comm is not None and self._exchange_plane is not None:
-            self._comm.stats.record_collective(
-                self._collective_bytes, stats["exchange_supersteps"])
-        self.last_run_stats = stats
-        self.report.run_stats = stats
-        return values, iters
+        with tracing.span("run"):
+            if checkpoint_dir is None:
+                checkpoint_dir = self._checkpoint_dir
+            if checkpoint_dir is not None:
+                return self._run_checkpointed(roots, values, checkpoint_dir,
+                                              checkpoint_every, resume)
+            with tracing.span("run.dispatch"):
+                values, active = self.init_state(roots=roots, values=values)
+                values, iters, stats_dev = self.run_loop(self._layouts, values,
+                                                         active)
+            # one host transfer for the whole counter tuple (a per-scalar
+            # int() would pay a device sync each)
+            with tracing.span("run.wait"):
+                iters, (pushes, compact, switches, pe_hi, pe_lo, pe_rows,
+                        pl_hi, pl_lo, bl_swept, bl_skip, pull_cost, live,
+                        nanfree) = jax.device_get((iters, stats_dev))
+            stats = self._scalar_stats(iters, pushes, compact, switches, pe_hi,
+                                       pe_lo, pe_rows, pl_hi, pl_lo, bl_swept,
+                                       bl_skip, pull_cost, live, nanfree)
+            if self._comm is not None and self._exchange_plane is not None:
+                self._comm.stats.record_collective(
+                    self._collective_bytes, stats["exchange_supersteps"])
+            self.last_run_stats = stats
+            self.report.run_stats = stats
+            return values, iters
 
     def _initial_lane_state(self, roots, values) -> BatchLaneState:
         """A 1-lane :class:`BatchLaneState` for run()'s request shapes."""
@@ -645,13 +661,13 @@ class CompiledGraphProgram:
             faults.trip("lane.crash",
                         payload={"iters": int(np.asarray(state.iters)[0])})
             state = self.run_batch_slice(state, every)
-            t0 = time.perf_counter()
             saves += 1
-            ckpt.write_snapshot(directory, "lane", seq,
-                                self.lane_snapshot(state),
-                                {"root": root_meta,
-                                 "checkpoint_saves": saves}, fps)
-            write_s += time.perf_counter() - t0
+            with tracing.span("run.checkpoint") as write:
+                ckpt.write_snapshot(directory, "lane", seq,
+                                    self.lane_snapshot(state),
+                                    {"root": root_meta,
+                                     "checkpoint_saves": saves}, fps)
+            write_s += write.seconds
             seq += 1
         host = jax.device_get(
             (state.iters, state.pushes, state.compact, state.switches,
@@ -1491,19 +1507,23 @@ def _emit_push_ell(ir: SuperstepIR, push_op: PushScatterOp,
         rows_per_v = layouts["fwd"]["rows_per_vertex"]
         return jnp.sum(jnp.where(active, rows_per_v, 0))[None]
 
-    def compacted_branch(capacity):
+    def compacted_branch(i, capacity):
+        stage = f"push.tier{i}"
+
         def branch(layouts, values, active):
             fwd = layouts["fwd"]
-            red, _ = push_ell_kernel.push_ell_reduce(
-                fwd["row_src"], fwd["dst"], fwd["wgt"], values,
-                layouts["deg"],
-                active, num_rows=fe.num_rows, capacity=capacity,
-                gather_fn=gather_fn, reduce=reduce_op, identity=ident,
-                num_vertices=V, dtype=dtype, gather_module=gather_module,
-                use_pallas=use_pallas, interpret=interpret,
-                emit_touched=False)
-            new = apply_fn(values, red)
-            return new, new != values
+            with tracing.stage(stage):
+                red, _ = push_ell_kernel.push_ell_reduce(
+                    fwd["row_src"], fwd["dst"], fwd["wgt"], values,
+                    layouts["deg"],
+                    active, num_rows=fe.num_rows, capacity=capacity,
+                    gather_fn=gather_fn, reduce=reduce_op, identity=ident,
+                    num_vertices=V, dtype=dtype, gather_module=gather_module,
+                    use_pallas=use_pallas, interpret=interpret,
+                    emit_touched=False, stage=stage)
+                with tracing.stage(stage + ".apply"):
+                    new = apply_fn(values, red)
+                    return new, new != values
         return branch
 
     def dense_fallback(layouts, values, active):
@@ -1511,16 +1531,21 @@ def _emit_push_ell(ir: SuperstepIR, push_op: PushScatterOp,
         # fwd_ell layout already guarantees an identity-fixpoint apply,
         # so applying everywhere matches take-if-touched bit-for-bit and
         # the unused `got` chain is dead code XLA deletes
-        red, _ = pull_reduce_module(layouts, values, active)
-        new = apply_fn(values, red)
-        return new, new != values
+        with tracing.stage("push.fallback"):
+            with tracing.stage("push.fallback.sweep"):
+                red, _ = pull_reduce_module(layouts, values, active)
+            with tracing.stage("push.fallback.apply"):
+                new = apply_fn(values, red)
+                return new, new != values
 
-    branches = [compacted_branch(c) for c in tiers] + [dense_fallback]
+    branches = [compacted_branch(i, c) for i, c in enumerate(tiers)] \
+        + [dense_fallback]
 
     @jax.jit
     def push_superstep(layouts, values, active):
-        r_f = live_rows(layouts, active)[0]
-        tier = sum((r_f > c).astype(jnp.int32) for c in tiers)
+        with tracing.stage("loop.direction"):
+            r_f = live_rows(layouts, active)[0]
+            tier = sum((r_f > c).astype(jnp.int32) for c in tiers)
         return jax.lax.switch(tier, branches, layouts, values, active)
 
     return push_superstep, tiers, live_rows
@@ -1579,25 +1604,29 @@ def _emit_push_ell_sharded(ir: SuperstepIR, push_op: PushScatterOp,
 
     def pe_partial(rs, dstb, wgtb, valid, out_deg, values, active):
         rs, dstb, wgtb, valid = rs[0], dstb[0], wgtb[0], valid[0]
-        live = active[rs] & valid       # interval-local live-row mask
-        r_f = jnp.sum(live.astype(jnp.int32))
 
-        def branch(capacity):
+        def branch(i, capacity):
             def b(values):
-                red, _ = push_ell_kernel.compacted_push_reduce(
-                    rs, dstb, wgtb, live, values, out_deg,
-                    num_rows=rp, capacity=capacity, gather_fn=gather_fn,
-                    reduce=reduce_op, identity=ident, num_vertices=V,
-                    dtype=dtype, gather_module=gather_module,
-                    use_pallas=use_pallas, interpret=interpret,
-                    emit_touched=False)
-                return red
+                with tracing.stage(f"push.tier{i}"):
+                    red, _ = push_ell_kernel.compacted_push_reduce(
+                        rs, dstb, wgtb, live, values, out_deg,
+                        num_rows=rp, capacity=capacity, gather_fn=gather_fn,
+                        reduce=reduce_op, identity=ident, num_vertices=V,
+                        dtype=dtype, gather_module=gather_module,
+                        use_pallas=use_pallas, interpret=interpret,
+                        emit_touched=False, stage=f"push.tier{i}")
+                    return red
             return b
 
-        tier = sum((r_f > c).astype(jnp.int32) for c in tiers[:-1])
-        red = jax.lax.switch(tier, [branch(c) for c in tiers], values)
+        with tracing.stage("loop.direction"):
+            live = active[rs] & valid   # interval-local live-row mask
+            r_f = jnp.sum(live.astype(jnp.int32))
+            tier = sum((r_f > c).astype(jnp.int32) for c in tiers[:-1])
+        red = jax.lax.switch(tier, [branch(i, c) for i, c in
+                                    enumerate(tiers)], values)
         # disjoint partials: the reduce-matched collective is exact
-        return collective(red, "pe")
+        with tracing.stage("push.tier.exchange"):
+            return collective(red, "pe")
 
     def sharded_compacted(layouts, values, active):
         # unchecked: the vma checker cannot type the per-PE switch before
@@ -1611,20 +1640,25 @@ def _emit_push_ell_sharded(ir: SuperstepIR, push_op: PushScatterOp,
                                   out_specs=P())(
             fwd["row_src"], fwd["dst"], fwd["wgt"], fwd["row_valid"],
             layouts["deg"], values, active)
-        new = apply_fn(values, red)
-        return new, new != values
+        with tracing.stage("push.tier.apply"):
+            new = apply_fn(values, red)
+            return new, new != values
 
     def dense_fallback(layouts, values, active):
         # the pull module's masked sweep, replicated (no exchange); the
         # identity-fixpoint apply lets it skip the touched mask (as in
         # the single-PE engine)
-        red, _ = pull_reduce_module(layouts, values, active)
-        new = apply_fn(values, red)
-        return new, new != values
+        with tracing.stage("push.fallback"):
+            with tracing.stage("push.fallback.sweep"):
+                red, _ = pull_reduce_module(layouts, values, active)
+            with tracing.stage("push.fallback.apply"):
+                new = apply_fn(values, red)
+                return new, new != values
 
     @jax.jit
     def push_superstep(layouts, values, active):
-        wide = jnp.max(live_rows_per_pe(layouts, active)) > tiers[-1]
+        with tracing.stage("loop.direction"):
+            wide = jnp.max(live_rows_per_pe(layouts, active)) > tiers[-1]
         return jax.lax.cond(wide, dense_fallback, sharded_compacted,
                             layouts, values, active)
 
@@ -1772,109 +1806,119 @@ def translate(
     repeat translate of identical inputs costs milliseconds;
     ``report.translate_breakdown`` itemizes where the time went.
     """
-    t0 = time.perf_counter()
-    schedule = schedule or ScheduleConfig()
-    comm = comm or CommManager()
-    if validate:
-        # opt-in structural validation — resident graphs check the CSR
-        # invariants directly; partition containers replay the same checks
-        # per partition on top of their always-on streamed-fetch checksums
-        if isinstance(g, G.Graph):
-            G.validate_graph(g, reduce=program.reduce)
-        elif hasattr(g, "validate_partitions"):
-            g.validate_partitions(reduce=program.reduce)
-    splan: SchedulePlan = plan(schedule, num_vertices=g.num_vertices,
-                               num_edges=g.num_edges,
-                               fixed_partitions=getattr(g, "partitions", None))
+    with tracing.span("translate") as whole:
+        schedule = schedule or ScheduleConfig()
+        comm = comm or CommManager()
+        if validate:
+            # opt-in structural validation — resident graphs check the CSR
+            # invariants directly; partition containers replay the same checks
+            # per partition on top of their always-on streamed-fetch checksums
+            if isinstance(g, G.Graph):
+                G.validate_graph(g, reduce=program.reduce)
+            elif hasattr(g, "validate_partitions"):
+                g.validate_partitions(reduce=program.reduce)
+        splan: SchedulePlan = plan(
+            schedule, num_vertices=g.num_vertices, num_edges=g.num_edges,
+            fixed_partitions=getattr(g, "partitions", None))
 
-    # out-of-core dispatch: a multi-partition plan, or a graph source that
-    # is not resident (a partition container), stages onto the streamed
-    # engine — translate-time and run-time data placement diverge there,
-    # so none of the resident emit paths below apply
-    if splan.num_partitions > 1 or not isinstance(g, G.Graph):
-        from . import stream
-        return stream.translate_partitioned(
-            program, g, schedule, splan, comm, use_pallas=use_pallas,
-            dump_passes=dump_passes, strict=strict,
-            checkpoint_dir=checkpoint_dir,
-            checkpoint_every=checkpoint_every)
+        # out-of-core dispatch: a multi-partition plan, or a graph source that
+        # is not resident (a partition container), stages onto the streamed
+        # engine — translate-time and run-time data placement diverge there,
+        # so none of the resident emit paths below apply
+        if splan.num_partitions > 1 or not isinstance(g, G.Graph):
+            from . import stream
+            return stream.translate_partitioned(
+                program, g, schedule, splan, comm, use_pallas=use_pallas,
+                dump_passes=dump_passes, strict=strict,
+                checkpoint_dir=checkpoint_dir,
+                checkpoint_every=checkpoint_every)
 
-    # ---- stages 1+2: lower to IR, run the pass pipeline -----------------
-    # (always re-run: the pipeline costs ~ms and keeps reports/dumps fresh)
-    t_passes0 = time.perf_counter()
-    ctx = PassContext(schedule=schedule, plan=splan, use_pallas=use_pallas,
-                      num_vertices=g.num_vertices, num_edges=g.num_edges)
-    ir, pipeline_report = default_pipeline().run(
-        lower_program(program), ctx, dump=dump_passes)
-    passes_s = time.perf_counter() - t_passes0
-    # the analysis pass's share of passes_s — near-zero on a fact-cache
-    # hit (templates are memoized, so repeat translates always hit)
-    analysis_s = next((r.time_s for r in pipeline_report.records
-                       if r.name == "program-analysis"), 0.0)
-    if strict and max_severity(ctx.diagnostics) in ("warning", "error"):
-        raise DiagnosticError(
-            f"strict translation rejected {program.name!r}: " +
-            "; ".join(d.render() for d in ctx.diagnostics
-                      if d.severity != "info"),
-            diagnostics=tuple(ctx.diagnostics))
+        # ---- stages 1+2: lower to IR, run the pass pipeline -------------
+        # (always re-run: the pipeline costs ~ms, keeps reports/dumps fresh)
+        with tracing.span("translate.passes") as passes:
+            ctx = PassContext(schedule=schedule, plan=splan,
+                              use_pallas=use_pallas,
+                              num_vertices=g.num_vertices,
+                              num_edges=g.num_edges)
+            ir, pipeline_report = default_pipeline().run(
+                lower_program(program), ctx, dump=dump_passes)
+        passes_s = passes.seconds
+        # the analysis pass's share of passes_s — near-zero on a fact-cache
+        # hit (templates are memoized, so repeat translates always hit)
+        analysis_s = next((r.time_s for r in pipeline_report.records
+                           if r.name == "program-analysis"), 0.0)
+        if strict and max_severity(ctx.diagnostics) in ("warning", "error"):
+            raise DiagnosticError(
+                f"strict translation rejected {program.name!r}: " +
+                "; ".join(d.render() for d in ctx.diagnostics
+                          if d.severity != "info"),
+                diagnostics=tuple(ctx.diagnostics))
 
-    fstep = ir.find(FusedSuperstepOp)
-    if fstep is not None:
-        fused, apply_op, frontier_op = fstep.fused, fstep.apply, \
-            fstep.frontier
-    else:
-        fused = ir.find(FusedGatherReduceOp)
-        apply_op = ir.find(ApplyOp)
-        frontier_op = ir.find(FrontierUpdateOp)
-    exchange_op = ir.find(ExchangeOp)
-    assert fused is not None and apply_op is not None \
-        and frontier_op is not None, "pass pipeline left the IR incomplete"
+        fstep = ir.find(FusedSuperstepOp)
+        if fstep is not None:
+            fused, apply_op, frontier_op = fstep.fused, fstep.apply, \
+                fstep.frontier
+        else:
+            fused = ir.find(FusedGatherReduceOp)
+            apply_op = ir.find(ApplyOp)
+            frontier_op = ir.find(FrontierUpdateOp)
+        exchange_op = ir.find(ExchangeOp)
+        assert fused is not None and apply_op is not None \
+            and frontier_op is not None, "pass pipeline left the IR incomplete"
 
-    dtype = ir.value_dtype
-    V = g.num_vertices
-    push_op = ir.find(PushScatterOp)
-    policy = splan.direction
+        dtype = ir.value_dtype
+        V = g.num_vertices
+        push_op = ir.find(PushScatterOp)
+        policy = splan.direction
 
-    key = _staging_key(program, g, schedule, use_pallas)
-    staged = _STAGING_CACHE.get(key) if key is not None else None
-    if staged is not None and _staging_hit(staged, g):
-        _STAGING_CACHE.move_to_end(key)
-        preprocess_s = emit_s = 0.0
-        cached = True
-    else:
-        lay = preprocess.layouts_for(g)
-        staged = _stage(program, ir, g, lay, schedule, splan, use_pallas,
-                        fstep, fused, apply_op, frontier_op, exchange_op,
-                        push_op if policy.mode != "pull" else None, comm)
-        preprocess_s = staged.pop("preprocess_s")
-        emit_s = staged.pop("emit_s")
-        cached = False
-        if key is not None:
-            _STAGING_CACHE[key] = staged
+        key = _staging_key(program, g, schedule, use_pallas)
+        staged = _STAGING_CACHE.get(key) if key is not None else None
+        if staged is not None and _staging_hit(staged, g):
             _STAGING_CACHE.move_to_end(key)
-            while len(_STAGING_CACHE) > _STAGING_CACHE_MAX:
-                _STAGING_CACHE.popitem(last=False)
+            preprocess_s = emit_s = 0.0
+            cached = True
+        else:
+            lay = preprocess.layouts_for(g)
+            with tracing.span("translate.emit") as emit:
+                staged = _stage(program, ir, g, lay, schedule, splan,
+                                use_pallas, fstep, fused, apply_op,
+                                frontier_op, exchange_op,
+                                push_op if policy.mode != "pull" else None,
+                                comm)
+            # the layouts built for this call are the spans nested in emit
+            preprocess_s, emit_s = emit.child_s, emit.self_s
+            # every layout this program needed came from the graph-keyed
+            # cache: 0.0 preprocess seconds means "reused", not "instant"
+            staged["preprocess_cached"] = preprocess_s == 0.0 \
+                and bool(lay.build_times_s)
+            cached = False
+            if key is not None:
+                _STAGING_CACHE[key] = staged
+                _STAGING_CACHE.move_to_end(key)
+                while len(_STAGING_CACHE) > _STAGING_CACHE_MAX:
+                    _STAGING_CACHE.popitem(last=False)
 
-    superstep = staged["superstep"]
-    push_superstep = staged["push_superstep"]
-    init_state = staged["init_state"]
-    layouts = staged["device_layouts"]
-    max_iters = schedule.superstep_budget(program.max_iters, V)
+        superstep = staged["superstep"]
+        push_superstep = staged["push_superstep"]
+        init_state = staged["init_state"]
+        layouts = staged["device_layouts"]
+        max_iters = schedule.superstep_budget(program.max_iters, V)
 
-    # AOT compile so translation time includes staging (paper's TT metric).
-    # Executing once (rather than .lower().compile()) populates the normal
-    # jit call cache, which the staging cache then reuses across repeats.
-    t_aot0 = time.perf_counter()
-    if aot_compile and not staged["aot_done"]:
-        v0, a0 = init_state(layouts, roots=0 if program.frontier == "changed"
-                            else None)
-        jax.block_until_ready(superstep(layouts, v0, a0))
-        if push_superstep is not None:
-            jax.block_until_ready(push_superstep(layouts, v0, a0))
-        staged["aot_done"] = True
-    aot_s = time.perf_counter() - t_aot0
+        # AOT compile so translation time includes staging (paper's TT
+        # metric).  Executing once (rather than .lower().compile())
+        # populates the normal jit call cache, which the staging cache then
+        # reuses across repeats.
+        with tracing.span("translate.aot") as aot:
+            if aot_compile and not staged["aot_done"]:
+                v0, a0 = init_state(layouts, roots=0 if program.frontier
+                                    == "changed" else None)
+                jax.block_until_ready(superstep(layouts, v0, a0))
+                if push_superstep is not None:
+                    jax.block_until_ready(push_superstep(layouts, v0, a0))
+                staged["aot_done"] = True
+        aot_s = aot.seconds
 
-    tt = time.perf_counter() - t0
+    tt = whole.seconds
     exchange_plane = staged["exchange_plane"]
     est_collective = comm.estimate_collective_bytes(
         V, dtype, staged["pes"] if exchange_plane is not None else 1,
@@ -1958,11 +2002,8 @@ def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
 
     Returns the staging-cache entry; graph-derived layouts come from
     ``lay`` (built on first use, memoized per graph) and are placed on the
-    device here (the entry's ``device_layouts``), and the entry's
-    ``preprocess_s`` records only the layout seconds spent *this* call.
+    device here (the entry's ``device_layouts``).
     """
-    pre_before = sum(lay.build_times_s.values())
-    t_emit0 = time.perf_counter()
     dtype = ir.value_dtype
     V = g.num_vertices
     # the graph arrays every staged function takes as its first argument
@@ -2090,10 +2131,11 @@ def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
     # eager per-run scatter chain cost ~1 ms of every run() call)
     @jax.jit
     def _rooted(values, roots):
-        root_val = jnp.asarray(0, dtype)
-        values = values.at[roots].set(root_val)
-        active = jnp.zeros((V,), bool).at[roots].set(True)
-        return values, active
+        with tracing.stage("init"):
+            root_val = jnp.asarray(0, dtype)
+            values = values.at[roots].set(root_val)
+            active = jnp.zeros((V,), bool).at[roots].set(True)
+            return values, active
 
     def init_state(layouts, roots=None, values=None):
         if values is None:
@@ -2103,9 +2145,6 @@ def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
         return values, jnp.ones((V,), bool)
 
     layouts = _place_layouts(host, comm, splan.mesh)
-
-    emit_s = time.perf_counter() - t_emit0
-    preprocess_s = sum(lay.build_times_s.values()) - pre_before
     return {
         "layouts": lay,
         "device_layouts": layouts,
@@ -2127,10 +2166,4 @@ def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
         "pull_blocks_total": pull_blocks_total,
         "loop_cache": {},
         "aot_done": False,
-        "preprocess_s": preprocess_s,
-        # every layout this program needed came from the graph-keyed
-        # cache: 0.0 preprocess seconds means "reused", not "instant"
-        "preprocess_cached": preprocess_s == 0.0
-        and bool(lay.build_times_s),
-        "emit_s": emit_s - preprocess_s,
     }

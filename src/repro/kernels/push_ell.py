@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core import graph as _G
+from ..core import tracing
 from .ref import GATHER_OPS, PAD, REDUCE_OPS, _gather_msg
 
 LANES = 128
@@ -175,6 +176,7 @@ def push_ell_reduce(
     use_pallas: bool = False,
     interpret: bool,
     emit_touched: bool = False,
+    stage: str = "push",    # device stage name (see repro.core.tracing)
 ) -> tuple[jax.Array, jax.Array | None]:
     """Frontier-compacted push superstep reduce.  Returns ``(red, touched)``.
 
@@ -190,15 +192,16 @@ def push_ell_reduce(
     """
     if reduce not in REDUCE_OPS:
         raise ValueError(reduce)
-    live = active[row_src]
-    if num_rows == 0:
-        live = jnp.zeros_like(live)
+    with tracing.stage(stage + ".compact"):
+        live = active[row_src]
+        if num_rows == 0:
+            live = jnp.zeros_like(live)
     return compacted_push_reduce(
         row_src, ell_dst, ell_wgt, live, values, degrees,
         num_rows=num_rows, capacity=capacity, gather_fn=gather_fn,
         reduce=reduce, identity=identity, num_vertices=num_vertices,
         dtype=dtype, gather_module=gather_module, use_pallas=use_pallas,
-        interpret=interpret, emit_touched=emit_touched)
+        interpret=interpret, emit_touched=emit_touched, stage=stage)
 
 
 def compacted_push_reduce(
@@ -220,6 +223,7 @@ def compacted_push_reduce(
     use_pallas: bool = False,
     interpret: bool,
     emit_touched: bool = False,
+    stage: str = "push",    # device stage name (see repro.core.tracing)
 ) -> tuple[jax.Array, jax.Array | None]:
     """Compaction → gather/message → segment-combine over a live-row mask.
 
@@ -229,33 +233,39 @@ def compacted_push_reduce(
     ``live`` mask is ``active[row_src] & row_valid`` — row ids stay local
     to the interval (the interval-offset form of compaction), while the
     destination ids in ``ell_dst`` remain global, so the returned table is
-    this PE's disjoint partial over the full vertex range.
+    this PE's disjoint partial over the full vertex range.  The three
+    steps are the device stages ``<stage>.compact``, ``<stage>.gather``
+    and ``<stage>.reduce``.
     """
     if reduce not in REDUCE_OPS:
         raise ValueError(reduce)
     identity = jnp.asarray(identity, dtype)
-    sel, ok = compact_rows(live, num_rows, capacity)
-    dst_blk = jnp.where(ok[None, :], ell_dst[:, sel], PAD)   # (W, cap)
-    wgt_blk = ell_wgt[:, sel]
-    src_blk = row_src[sel]
-    if use_pallas and gather_module is not None:
-        msg = push_ell_message_block(dst_blk.T, wgt_blk.T, src_blk, values,
-                                     degrees, gather=gather_module,
-                                     interpret=interpret).T
-    else:
-        msg = _messages_xla(dst_blk, wgt_blk, src_blk, values, degrees,
-                            gather_fn=gather_fn)
-    valid = dst_blk != PAD
-    segs = jnp.where(valid, dst_blk, num_vertices).reshape(-1)
-    flat = jnp.where(valid, msg.astype(dtype), identity).reshape(-1)
-    # jax's segment reduces fill empty segments with the op identity, which
-    # matches the folded identity for every (reduce, dtype) the legality
-    # pass admits; the PAD segment (num_vertices) is sliced off.
-    red = _SEGMENT_REDUCE[reduce](flat, segs,
-                                  num_segments=num_vertices + 1)[:num_vertices]
-    touched = None
-    if emit_touched:
-        touched = jax.ops.segment_max(
-            valid.reshape(-1).astype(jnp.int32), segs,
-            num_segments=num_vertices + 1)[:num_vertices] > 0
-    return red.astype(dtype), touched
+    with tracing.stage(stage + ".compact"):
+        sel, ok = compact_rows(live, num_rows, capacity)
+    with tracing.stage(stage + ".gather"):
+        dst_blk = jnp.where(ok[None, :], ell_dst[:, sel], PAD)  # (W, cap)
+        wgt_blk = ell_wgt[:, sel]
+        src_blk = row_src[sel]
+        if use_pallas and gather_module is not None:
+            msg = push_ell_message_block(dst_blk.T, wgt_blk.T, src_blk,
+                                         values, degrees,
+                                         gather=gather_module,
+                                         interpret=interpret).T
+        else:
+            msg = _messages_xla(dst_blk, wgt_blk, src_blk, values, degrees,
+                                gather_fn=gather_fn)
+    with tracing.stage(stage + ".reduce"):
+        valid = dst_blk != PAD
+        segs = jnp.where(valid, dst_blk, num_vertices).reshape(-1)
+        flat = jnp.where(valid, msg.astype(dtype), identity).reshape(-1)
+        # jax's segment reduces fill empty segments with the op identity,
+        # which matches the folded identity for every (reduce, dtype) the
+        # legality pass admits; the PAD segment (num_vertices) is sliced off
+        red = _SEGMENT_REDUCE[reduce](
+            flat, segs, num_segments=num_vertices + 1)[:num_vertices]
+        touched = None
+        if emit_touched:
+            touched = jax.ops.segment_max(
+                valid.reshape(-1).astype(jnp.int32), segs,
+                num_segments=num_vertices + 1)[:num_vertices] > 0
+        return red.astype(dtype), touched
