@@ -268,7 +268,7 @@ class ForwardELL:
 
     ``rows_per_vertex`` lets the runtime direction policy compute the live
     row count ``r_f = sum(rows_per_vertex[frontier])`` in O(V) — the guard
-    that picks a compaction capacity tier (or the dense fallback) per
+    that picks a compaction capacity tier (or the full push) per
     superstep.  When the graph has no edges the arrays keep one all-PAD
     dummy row so every shape stays non-empty; ``num_rows`` is the logical
     row count (0) and compaction treats the dummy as invalid.

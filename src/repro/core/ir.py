@@ -150,10 +150,10 @@ class PushScatterOp:
 
     * ``'fwd_ell'`` — the frontier-compacted forward-ELL engine
       (``kernels/push_ell.py``): cumsum-compacted active rows, capacity
-      tiers, segment-reduce combine, dense-engine fallback beyond the
-      largest tier.  Requires the dense backend and an identity-fixpoint
-      apply (``apply(x, identity) == x``, probed) since it skips the
-      touched-mask scatter.  This is also the only multi-PE-shardable
+      tiers, segment-reduce combine, a chunked full push over every row
+      beyond the largest tier.  Requires the dense backend and an
+      identity-fixpoint apply (``apply(x, identity) == x``, probed) since
+      it skips the touched-mask scatter.  This is also the only multi-PE-shardable
       layout: under ``pes > 1`` the translator partitions the forward ELL
       into disjoint per-PE row intervals and combines the partial tables
       with the reduce-matched collective (the IR's resolved

@@ -54,24 +54,25 @@ class DirectionPolicy:
     tuning meant raising alpha well above the traversal model.  The
     compacted engine removed that asymmetry: a push superstep costs
     ``O(R + capacity·width)`` when a capacity tier covers the live rows
-    (``r_f``, see :func:`push_capacity_tiers`) and *at most* the dense
-    engine's O(E) otherwise (the kernel falls back to the dense masked
-    sweep rather than scatter a wide frontier).  Push is therefore never
-    meaningfully slower than pull, and the thresholds revert to the pure
-    traversal model — the paper's hardware cost, where an FPGA frontier
-    FIFO streams only live edges and ``report.run_stats['edges_traversed']``
-    counts ``m_f`` per push superstep:
+    (``r_f``, see :func:`push_capacity_tiers`) and one O(E) full push
+    otherwise — one gather per row and one scatter per slot, which on a
+    v5e undercuts the dense pull sweep's one gather per slot.  Push is
+    therefore never meaningfully slower than pull, and the thresholds
+    revert to the pure traversal model — the paper's hardware cost, where
+    an FPGA frontier FIFO streams only live edges and
+    ``report.run_stats['edges_traversed']`` counts ``m_f`` per push
+    superstep:
 
     * ``alpha=1.0`` — push pays (in traversals) exactly while the
       frontier's out-edges are fewer than E;
     * ``beta=4.0`` — enter push as soon as the frontier drains below V/4
-      (entry risk is bounded by the dense fallback, so entering early is
+      (entry risk is bounded by the full push, so entering early is
       cheap; all-active starts like WCC begin pull and flip to push as
       labels converge).
 
-    ``benchmarks/direction.py`` re-derives this calibration from measured
-    per-edge costs (pull stream vs compacted push vs fallback) and records
-    them in ``BENCH_graph.json``'s crossover section.  Push mode
+    ``benchmarks/direction.py`` re-derives this calibration from per-edge
+    costs measured on XLA:CPU (pull stream vs compacted push vs fallback)
+    and records them in ``BENCH_graph.json``'s crossover section.  Push mode
     additionally requires the program to pass the translator's
     direction-legality analysis; illegal programs run pull regardless.
 
@@ -284,11 +285,16 @@ def push_capacity_tiers(num_rows: int) -> tuple[int, int]:
     sized for the widest frontier would erase the savings on sparse ones.
     Two power-of-two tiers — ``~R/64`` and ``~R/16`` rows — let the
     runtime pick the smallest tier covering the live row count ``r_f``
-    each superstep; beyond the large tier the push superstep falls back to
-    the dense masked sweep (cost ≈ pull), because on XLA backends the
-    per-edge scatter (~90 ns measured on CPU) overtakes the dense stream
-    (~15 ns/slot) long before ``r_f·width`` reaches E.  Derived from the
-    forward-ELL row count so the tiers track graph shape, not raw E.
+    each superstep.  Every compacted superstep also pays the O(R)
+    live-row gather ``active[row_src]`` (~20 ns/row on a v5e), so beyond
+    the large tier the push superstep takes the *full push* instead
+    (``push_ell.full_push_reduce``): every row in chunks of the large
+    tier's capacity, no compaction.  Per element on a v5e (TPU traces of
+    Graph500 BFS at scale 22 and SSSP at scale 21): the segment-combine
+    scatter ~6-7 ns per slot, a vertex-table gather ~20 ns per row
+    (~2.5 ns per slot at width 8), the dense pull sweep's gather it
+    replaced ~10-19 ns per slot.  Derived from the forward-ELL row count
+    so the tiers track graph shape, not raw E.
 
     Under a multi-PE plan the translator passes the *largest interval's*
     row count (``ShardedForwardELL.rows_per_pe_max``): ``shard_map``

@@ -41,8 +41,8 @@ The TPU mapping implemented here:
   (``G.reverse``); the push superstep is the frontier-compacted forward-ELL
   engine (``kernels/push_ell.py``): live rows compact into a capacity tier
   picked per superstep from the live row count, and frontiers wider than
-  the largest tier fall back to the dense masked sweep, so push never
-  costs meaningfully more than pull.
+  the largest tier take the full push (every row, one vertex-table
+  gather per row), so push never costs meaningfully more than pull.
 * **Multi-PE push** — under a ``pes > 1`` plan the push plane shards: the
   forward ELL splits into per-PE contiguous, degree-balanced row
   intervals (:func:`~repro.core.graph.shard_forward_ell`), each PE runs
@@ -711,8 +711,9 @@ class CompiledGraphProgram:
         The cost trade-off is explicit: under vmap both the direction
         ``cond`` and the tier ``switch`` lower to execute-all-branches
         selects, so each batched auto superstep pays the pull module,
-        both compacted tiers, *and* the dense fallback (≈2× a pull-pinned
-        batch, vs ~7× with the old O(E) chunk-scan push).  Pin
+        both compacted tiers, *and* the full push (measured ≈2× a
+        pull-pinned batch on XLA:CPU with the dense-sweep fallback the
+        full push replaced, vs ~7× with the old O(E) chunk-scan push).  Pin
         ``DirectionPolicy(mode='pull')`` when batched throughput matters
         more than per-lane direction stats — results are bit-identical
         either way.
@@ -984,21 +985,23 @@ class CompiledGraphProgram:
 # ---------------------------------------------------------------------------
 
 
-def _flat_message_mode(ir: SuperstepIR, fused: FusedGatherReduceOp,
-                       program, dtype) -> str:
-    """Pick the flat sweep's per-edge message form (all bit-identical):
+def _flat_message_mode(ir: SuperstepIR) -> str:
+    """Pick the message form of the flat pull sweep and of the full push
+    (``push_ell.MESSAGE_FORMS``; all bit-identical):
 
     * ``'table'`` — weight-free gather (the analyzer's ``weight_use``
       fact, not a hardcoded menu-name list, so *any* user gather whose
       jaxpr never reads the weight qualifies): messages precompute into
-      a ``(V+1,)`` masked table, the sweep is ONE gather per slot;
+      a ``(V+1,)`` masked table, read by ONE gather per slot (pull) or
+      per row (push);
     * ``'masked'`` — the reduce identity absorbs through the gather
       (the ``identity_absorbing`` fact — e.g. SSSP's ``inf + w == inf``):
       the *value* table is pre-masked once and the gather evaluates per
       edge with no separate frontier gather;
-    * ``'classic'`` — everything else: per-edge value/degree/frontier
-      gathers with explicit identity masking.
+    * ``'classic'`` — everything else: value/degree/frontier gathers
+      with explicit identity masking.
     """
+    program = ir.program
     facts = ir.facts if ir.facts is not None else analyze_program(program)
     if facts.weight_use.value is False:
         return "table"
@@ -1094,7 +1097,7 @@ def _emit_dense_pull_reduce(ir: SuperstepIR, fused: FusedGatherReduceOp,
     op = fused.reduce.op
     gather_module = fused.gather.module
     gather_fn = fused.gather.fn
-    mode = _flat_message_mode(ir, fused, program, dtype)
+    mode = _flat_message_mode(ir)
     rop = ROW_REDUCE[op]
 
     def sub_sweep(values, active, out_deg, dst_blk, wgt_blk):
@@ -1106,7 +1109,7 @@ def _emit_dense_pull_reduce(ir: SuperstepIR, fused: FusedGatherReduceOp,
         runs with no per-superstep PAD masking temp.
         """
         if mode == "table":
-            msg_t = pull_bitmap_kernel.message_table(
+            msg_t = push_ell_kernel.message_table(
                 values, out_deg, active, gather=gather_module,
                 gather_fn=gather_fn, reduce=op,
                 mask_inactive=program.mask_inactive, dtype=dtype)
@@ -1474,21 +1477,25 @@ def _emit_push_scatter(ir: SuperstepIR, push_op: PushScatterOp, g: G.Graph,
 
 
 def _emit_push_ell(ir: SuperstepIR, push_op: PushScatterOp,
-                   fe: G.ForwardELL, apply_fn, pull_reduce_module,
-                   use_pallas: bool):
+                   fe: G.ForwardELL, apply_fn, use_pallas: bool):
     """Emit the frontier-compacted forward-ELL push superstep.
 
     Builds the tiered push superstep: the live forward-ELL row count
     ``r_f`` picks, per superstep, the smallest compaction capacity tier
-    that covers the frontier (``kernels/push_ell.py`` does cumsum
-    compaction → gather → segment-reduce), or the *dense fallback* — the
-    same masked sweep as the pull module — when the frontier is too wide
-    for compaction to beat the dense stream.  All branches compute the
-    identical superstep function, so the tier choice (like the direction
-    choice) is invisible in the results.
+    that covers the frontier (``kernels/push_ell.py`` does bitmap
+    compaction → gather → segment-combine), or, when the frontier is too
+    wide for compaction to pay, the *full push*
+    (``push_ell.full_push_reduce``): every row in storage order, in
+    chunks of the large tier's capacity, one vertex-table gather per row
+    and the same segment-combine per slot.  On a v5e that costs ~2.5
+    ns/slot of row gathers plus ~6 ns/slot of scatter, against ~10-19
+    ns/slot for the dense pull sweep's per-slot gather it replaced (see
+    :func:`~repro.core.scheduler.push_capacity_tiers`).  All branches
+    compute the identical superstep function, so the tier choice (like
+    the direction choice) is invisible in the results.
 
-    The compacted branches apply the reduced table *everywhere* and skip
-    the touched mask entirely — sound because the fusion pass binds the
+    Every branch applies the reduced table *everywhere* and skips the
+    touched mask entirely — sound because the fusion pass binds the
     ``fwd_ell`` layout only after probing ``apply(x, identity) == x``
     (untouched vertices are fixpoints).  Reads ``layouts["fwd"]``.
     Returns ``(push_superstep, tiers, live_rows)``, where ``live_rows``
@@ -1500,6 +1507,7 @@ def _emit_push_ell(ir: SuperstepIR, push_op: PushScatterOp,
     gather_fn = push_op.gather.fn
     gather_module = push_op.gather.module
     reduce_op = push_op.reduce.op
+    form = _flat_message_mode(ir)
     tiers = push_capacity_tiers(fe.num_rows)
     interpret = jax.default_backend() != "tpu"
 
@@ -1526,20 +1534,23 @@ def _emit_push_ell(ir: SuperstepIR, push_op: PushScatterOp,
                     return new, new != values
         return branch
 
-    def dense_fallback(layouts, values, active):
-        # the pull module's masked sweep (bit-identical reduce); the
-        # fwd_ell layout already guarantees an identity-fixpoint apply,
-        # so applying everywhere matches take-if-touched bit-for-bit and
-        # the unused `got` chain is dead code XLA deletes
+    def full_push(layouts, values, active):
+        fwd = layouts["fwd"]
         with tracing.stage("push.fallback"):
-            with tracing.stage("push.fallback.sweep"):
-                red, _ = pull_reduce_module(layouts, values, active)
+            red = push_ell_kernel.full_push_reduce(
+                fwd["row_src"], fwd["dst"], fwd["wgt"], values,
+                layouts["deg"], active, num_rows=fe.num_rows,
+                chunk=tiers[-1], form=form, gather_fn=gather_fn,
+                reduce=reduce_op, identity=ident, num_vertices=V,
+                dtype=dtype, gather_module=gather_module,
+                mask_inactive=ir.program.mask_inactive,
+                stage="push.fallback")
             with tracing.stage("push.fallback.apply"):
                 new = apply_fn(values, red)
                 return new, new != values
 
     branches = [compacted_branch(i, c) for i, c in enumerate(tiers)] \
-        + [dense_fallback]
+        + [full_push]
 
     @jax.jit
     def push_superstep(layouts, values, active):
@@ -2116,7 +2127,7 @@ def _stage(program, ir, g, lay, schedule, splan, use_pallas, fstep, fused,
             else:
                 host["fwd"] = _fwd_device_arrays(fe)
                 push_superstep, push_tiers, push_rf_fn = _emit_push_ell(
-                    ir, push_op, fe, apply_fn, reduce_module, use_pallas)
+                    ir, push_op, fe, apply_fn, use_pallas)
         else:
             scatter, host["push_coo"] = _emit_push_scatter(ir, push_op, g,
                                                            splan)

@@ -44,14 +44,12 @@ proportional to live blocks.  Both are bit-exact against the dense sweep.
 """
 from __future__ import annotations
 
-from typing import Callable
-
 import jax
 import jax.numpy as jnp
 
 from ..core import graph as _G
 from .push_ell import compact_rows
-from .ref import PAD, ROW_REDUCE, _identity, gather_msg
+from .ref import PAD, ROW_REDUCE
 
 
 def touched_table(row_src: jax.Array, ell_dst: jax.Array, active: jax.Array,
@@ -161,35 +159,3 @@ def subrow_combine(sub_red: jax.Array, plan, identity, reduce: str,
     parts.append(jnp.full((1,), identity, dtype))
     return combine_rows(jnp.concatenate(parts).astype(dtype), plan,
                         identity, reduce, dtype)
-
-
-def message_table(values: jax.Array, degrees: jax.Array, active: jax.Array,
-                  *, gather: str | None, gather_fn: Callable | None,
-                  reduce: str, mask_inactive: bool, dtype) -> jax.Array:
-    """Per-source message table for weight-free gathers: ``(V+1,)`` with
-    messages masked to the reduce identity for inactive sources and the
-    identity in the PAD slot (index V).
-
-    A weight-free gather's message depends only on its source, so the
-    sweep collapses to ONE gather from this table per edge slot instead
-    of separate value/degree/frontier gathers plus per-edge arithmetic —
-    bit-identical (same elementwise ops on the same operands, computed
-    once per vertex instead of once per edge).
-    """
-    ones = jnp.ones((values.shape[0],), plan_weight_dtype(values))
-    if gather is not None:
-        msg = gather_msg(gather, values, ones.astype(values.dtype), degrees)
-    else:
-        msg = gather_fn(values, ones.astype(values.dtype), degrees)
-    ident = jnp.asarray(_identity(reduce, jnp.dtype(dtype)), dtype)
-    if mask_inactive:
-        msg = jnp.where(active, msg.astype(dtype), ident)
-    else:
-        msg = msg.astype(dtype)
-    return jnp.concatenate([msg, ident[None]])
-
-
-def plan_weight_dtype(values):
-    """Weight placeholder dtype for weight-free message tables."""
-    return values.dtype if jnp.issubdtype(values.dtype, jnp.floating) \
-        else jnp.float32

@@ -18,8 +18,9 @@ instead of a dense sweep guarded per chunk.  Three stages:
    edge-processing unit proper — same VMEM vertex-table addressing as
    ``edge_block.py``); elsewhere the XLA form is used.
 3. **Scatter-combine** — messages land in the per-vertex table via a
-   segment reduce over destination ids (``jax.ops.segment_min/max/sum``
-   with PAD slots routed to a dummy segment).  A full sort by destination
+   reduce-matched scatter over destination ids (:func:`segment_combine`,
+   ``.at[].min/max/add`` with PAD slots routed to a dummy slot; on a
+   v5e ~6 ns per slot).  A full sort by destination
    would allow a sorted segment reduce, but measured on XLA:CPU the sort
    (~300 ns/edge) dwarfs the unsorted segment reduce (~90 ns/edge), so the
    sort is reserved for backends where it pays.
@@ -33,8 +34,10 @@ row-major arrays and transposes them).
 
 Work is O(R + capacity·width) per superstep instead of O(E): the runtime
 direction policy picks a capacity tier that covers the live row count
-``r_f`` (or falls back to the dense engine when the frontier is too wide
-for compaction to pay — see ``translator._emit_push_ell``).
+``r_f``.  A frontier too wide for the largest tier takes
+:func:`full_push_reduce` instead: every row in storage order, chunk by
+chunk, with no compaction — one vertex-table gather per *row* and the
+same segment-combine per slot (see ``translator._emit_push_ell``).
 
 ``kernels.ref.push_scatter_reduce_ref`` remains the numeric oracle: for any
 frontier and ``capacity >= r_f`` the compacted result equals the dense
@@ -52,12 +55,36 @@ from jax.experimental import pallas as pl
 
 from ..core import graph as _G
 from ..core import tracing
-from .ref import GATHER_OPS, PAD, REDUCE_OPS, _gather_msg
+from .ref import GATHER_OPS, PAD, REDUCE_OPS, _gather_msg, _identity
 
 LANES = 128
 
-_SEGMENT_REDUCE = {"add": jax.ops.segment_sum, "min": jax.ops.segment_min,
-                   "max": jax.ops.segment_max}
+# How a push computes a row's slot messages (all bit-identical; the
+# translator picks one from the program's analyzed facts):
+# ``table`` — weight-free gather: one gather of the masked per-vertex
+# message table; ``masked`` — the reduce identity absorbs through the
+# gather (SSSP's ``inf + w == inf``): one gather of the pre-masked value
+# table, the weight read per slot; ``classic`` — value, degree and
+# frontier gathered, identity-masked per slot.
+MESSAGE_FORMS = ("table", "masked", "classic")
+
+_SCATTER = {"add": lambda t, i, x: t.at[i].add(x),
+            "min": lambda t, i, x: t.at[i].min(x),
+            "max": lambda t, i, x: t.at[i].max(x)}
+
+
+def segment_combine(table: jax.Array, dst_blk: jax.Array, msg: jax.Array,
+                    reduce: str) -> jax.Array:
+    """⊕-scatter a slot-major ``(W, n)`` message block into ``table``.
+
+    ``table`` is a ``(V+1,)`` per-vertex table; each slot's message lands
+    at its destination and PAD slots land in the dummy last slot ``V``,
+    which callers slice off.  The push plane's one combine: the compacted
+    tiers scatter into a fresh identity table, the full push carries one
+    table across its chunks.
+    """
+    segs = jnp.where(dst_blk != PAD, dst_blk, table.shape[0] - 1)
+    return _SCATTER[reduce](table, segs.reshape(-1), msg.reshape(-1))
 
 
 def compact_rows(live: jax.Array, num_rows: int, capacity: int
@@ -255,17 +282,125 @@ def compacted_push_reduce(
             msg = _messages_xla(dst_blk, wgt_blk, src_blk, values, degrees,
                                 gather_fn=gather_fn)
     with tracing.stage(stage + ".reduce"):
-        valid = dst_blk != PAD
-        segs = jnp.where(valid, dst_blk, num_vertices).reshape(-1)
-        flat = jnp.where(valid, msg.astype(dtype), identity).reshape(-1)
-        # jax's segment reduces fill empty segments with the op identity,
-        # which matches the folded identity for every (reduce, dtype) the
-        # legality pass admits; the PAD segment (num_vertices) is sliced off
-        red = _SEGMENT_REDUCE[reduce](
-            flat, segs, num_segments=num_vertices + 1)[:num_vertices]
+        table = jnp.full((num_vertices + 1,), identity, dtype)
+        red = segment_combine(table, dst_blk, msg.astype(dtype),
+                              reduce)[:num_vertices]
         touched = None
         if emit_touched:
-            touched = jax.ops.segment_max(
-                valid.reshape(-1).astype(jnp.int32), segs,
-                num_segments=num_vertices + 1)[:num_vertices] > 0
-        return red.astype(dtype), touched
+            touched = segment_combine(
+                jnp.zeros((num_vertices + 1,), jnp.int32), dst_blk,
+                (dst_blk != PAD).astype(jnp.int32), "max")[:num_vertices] > 0
+        return red, touched
+
+
+def message_table(values: jax.Array, degrees: jax.Array, active: jax.Array,
+                  *, gather: str | None, gather_fn: Callable | None,
+                  reduce: str, mask_inactive: bool, dtype) -> jax.Array:
+    """Per-source message table for weight-free gathers: ``(V+1,)`` with
+    messages masked to the reduce identity for inactive sources and the
+    identity in the PAD slot (index V).
+
+    A weight-free gather's message depends only on its source, so a sweep
+    or a push collapses to ONE gather from this table (per edge slot in
+    the pull sweep, per row in :func:`full_push_reduce`) instead of
+    separate value/degree/frontier gathers plus per-edge arithmetic —
+    bit-identical (same elementwise ops on the same operands, computed
+    once per vertex instead of once per edge).
+    """
+    ones = jnp.ones((values.shape[0],), values.dtype)
+    if gather is not None:
+        msg = _gather_msg(gather, values, ones, degrees)
+    else:
+        msg = gather_fn(values, ones, degrees)
+    ident = jnp.asarray(_identity(reduce, jnp.dtype(dtype)), dtype)
+    if mask_inactive:
+        msg = jnp.where(active, msg.astype(dtype), ident)
+    else:
+        msg = msg.astype(dtype)
+    return jnp.concatenate([msg, ident[None]])
+
+
+def full_push_reduce(
+    row_src: jax.Array,     # (max(R,1),) int32 owner vertex per ELL row
+    ell_dst: jax.Array,     # (W, max(R,1)) int32 destinations, PAD-padded
+    ell_wgt: jax.Array,     # (W, max(R,1)) edge weights
+    values: jax.Array,      # (V,) vertex values
+    degrees: jax.Array,     # (V,) out-degrees (gather's third argument)
+    active: jax.Array,      # (V,) bool frontier
+    *,
+    num_rows: int,          # logical R (0 for an edgeless graph)
+    chunk: int,             # rows per chunk (static)
+    form: str,              # one of MESSAGE_FORMS
+    gather_fn: Callable,    # (src_value, weight, degree) -> message
+    reduce: str,            # 'add' | 'min' | 'max'
+    identity,               # folded reduce identity (scalar, value dtype)
+    num_vertices: int,
+    dtype,
+    gather_module: str | None = None,   # menu name (the table's gather)
+    mask_inactive: bool = True,
+    stage: str = "push",    # device stage name (see repro.core.tracing)
+) -> jax.Array:
+    """Push over *every* forward-ELL row: the reduced ``(V,)`` table.
+
+    The wide-frontier superstep, where compaction no longer pays: rows
+    are taken in storage order, ``chunk`` at a time (no compaction, no
+    copy of the layout), and each chunk gathers ONE per-vertex message
+    per row from a table built once per call (``form``, see
+    :data:`MESSAGE_FORMS`), broadcasts it across the row's slots and
+    folds the slots into a ``(V+1,)`` table carried across the chunks
+    with :func:`segment_combine`.  Inactive sources contribute the identity.
+
+    The last chunk is clamped to end at row ``R`` (``dynamic_slice``
+    needs it in bounds); its rows that an earlier chunk already took
+    contribute the identity, so an ``add`` counts each edge once.  For
+    min/max and integer add the result equals the dense pull sweep and
+    :func:`repro.kernels.ref.push_scatter_reduce_ref` bit for bit.
+    Device stages ``<stage>.gather`` (tables and per-row gathers) and
+    ``<stage>.reduce`` (the scatter).
+    """
+    if reduce not in REDUCE_OPS:
+        raise ValueError(reduce)
+    if form not in MESSAGE_FORMS:
+        raise ValueError(form)
+    ident = jnp.asarray(identity, dtype)
+    width = ell_dst.shape[0]
+    rows = max(min(chunk, num_rows), 1)
+    with tracing.stage(stage + ".gather"):
+        if form == "table":
+            src_t = message_table(values, degrees, active,
+                                  gather=gather_module, gather_fn=gather_fn,
+                                  reduce=reduce, mask_inactive=mask_inactive,
+                                  dtype=dtype)
+        elif form == "masked":
+            src_t = jnp.where(active, values, ident)
+
+    def body(i, table):
+        start = jnp.minimum(i * rows, num_rows - rows)
+        with tracing.stage(stage + ".gather"):
+            fresh = start + jnp.arange(rows) >= i * rows
+            rs = jax.lax.dynamic_slice_in_dim(row_src, start, rows)
+            dst = jax.lax.dynamic_slice_in_dim(ell_dst, start, rows, axis=1)
+            shape = (width, rows)
+            if form == "table":
+                msg = jnp.where(fresh, src_t[rs], ident)[None, :]
+            else:
+                wgt = jax.lax.dynamic_slice_in_dim(ell_wgt, start, rows,
+                                                   axis=1).astype(dtype)
+                deg = jnp.broadcast_to(degrees[rs][None, :], shape)
+                if form == "masked":
+                    v = jnp.where(fresh, src_t[rs], ident)
+                    msg = gather_fn(jnp.broadcast_to(v[None, :], shape), wgt,
+                                    deg).astype(dtype)
+                else:
+                    live = fresh & active[rs] if mask_inactive else fresh
+                    v = jnp.broadcast_to(values[rs][None, :], shape)
+                    msg = jnp.where(live[None, :],
+                                    gather_fn(v, wgt, deg).astype(dtype),
+                                    ident)
+            msg = jnp.broadcast_to(msg, shape)
+        with tracing.stage(stage + ".reduce"):
+            return segment_combine(table, dst, msg, reduce)
+
+    table = jnp.full((num_vertices + 1,), ident, dtype)
+    trips = -(-num_rows // rows)
+    return jax.lax.fori_loop(0, trips, body, table)[:num_vertices]

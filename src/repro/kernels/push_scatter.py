@@ -24,7 +24,8 @@ translator stages into the push superstep for the *sparse* backend
 (``PushScatterOp.layout == 'coo_chunks'``).  The dense backend uses the
 frontier-compacted forward-ELL engine in :mod:`repro.kernels.push_ell`
 instead — data-indexed compaction (no ``lax.cond``), capacity tiers, and
-a dense fallback, which is both faster per live edge and vmap-friendly.
+a chunked full push for wide frontiers, which is both faster per live
+edge and vmap-friendly.
 """
 from __future__ import annotations
 

@@ -1,8 +1,9 @@
 """Frontier-compacted forward-ELL push engine: layout construction,
 cumsum compaction, kernel ≡ dense-scatter oracle, the preprocessing cache,
 and the translate-time breakdown / staging cache."""
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import algorithms as alg
@@ -14,7 +15,8 @@ from repro.core.scheduler import (DirectionPolicy, ScheduleConfig,
 from repro.core.translator import translate
 from repro.kernels import ops as kops
 from repro.kernels import push_ell as pk
-from repro.kernels.ref import GATHER_OPS, REDUCE_OPS, push_scatter_reduce_ref
+from repro.kernels.ref import (GATHER_OPS, REDUCE_OPS, _identity, gather_msg,
+                               push_scatter_reduce_ref)
 
 PAD = jnp.iinfo(jnp.int32).max
 
@@ -242,6 +244,148 @@ def test_push_ell_property(dtype):
 
 
 # ---------------------------------------------------------------------------
+# 3b. full push (the wide-frontier branch) ≡ dense pull sweep ≡ oracle
+# ---------------------------------------------------------------------------
+
+FULL_V = 48
+# out-degree (7i mod 19) for vertex i: 429 edges in R = 75 width-8 rows
+FULL_DEG = (np.arange(FULL_V) * 7) % 19
+# chunk sizes against R = 75: 3 whole chunks, 3 chunks with the last one
+# clamped onto 21 rows the second already took, one chunk past R
+FULL_CHUNKS = {"multiple": 25, "ragged": 32, "one_chunk": 128}
+# (reduce, form) -> (menu gather, value dtype): the table form needs a
+# weight-free gather, the masked form one the reduce identity absorbs
+# through (inf + w, -inf + w, 0 * w); the classic form takes any gather
+FULL_CASES = {
+    ("min", "table"): ("plus_one", np.float32),
+    ("min", "masked"): ("add_w", np.float32),
+    ("min", "classic"): ("add_w", np.float32),
+    ("max", "table"): ("plus_one", np.float32),
+    ("max", "masked"): ("add_w", np.float32),
+    ("max", "classic"): ("add_w", np.float32),
+    ("add", "table"): ("plus_one", np.int32),
+    ("add", "masked"): ("mul_w", np.int32),
+    ("add", "classic"): ("add_w", np.int32),
+}
+_FULL_FNS: dict = {}
+
+
+def _full_graph(layout):
+    if layout == "edgeless":
+        none = np.zeros(0, np.int32)
+        return G.from_edge_list(none, none, num_vertices=FULL_V)
+    rng = np.random.default_rng(41)
+    src = np.repeat(np.arange(FULL_V, dtype=np.int32), FULL_DEG)
+    dst = rng.integers(0, FULL_V, len(src)).astype(np.int32)
+    w = rng.uniform(0.5, 3, len(src)).astype(np.float32)
+    return G.from_edge_list(src, dst, num_vertices=FULL_V, weights=w)
+
+
+def _dense_pull_sweep(g, gather, reduce, dtype):
+    """The pull plane's dense sweep (the push plane's wide-frontier branch
+    before the full push) as a jitted ``(values, active) -> red``."""
+    from repro.core import translator as tr
+    from repro.core.ir import FusedGatherReduceOp, lower_program
+    from repro.core.passes import PassContext, default_pipeline
+    from repro.core.scheduler import plan
+
+    apply = {"min": jnp.minimum, "max": jnp.maximum, "add": jnp.add}[reduce]
+    program = dsl.VertexProgram(
+        name=f"sweep_{gather}_{reduce}",
+        gather=lambda v, w, d: gather_msg(gather, v, w, d), reduce=reduce,
+        apply=apply, init_value=0, value_dtype=dtype)
+    cfg = ScheduleConfig()
+    ctx = PassContext(schedule=cfg, plan=plan(
+        cfg, num_vertices=g.num_vertices, num_edges=g.num_edges),
+        use_pallas=False, num_vertices=g.num_vertices,
+        num_edges=g.num_edges)
+    ir, _ = default_pipeline().run(lower_program(program), ctx)
+    fused = ir.find(FusedGatherReduceOp)
+    if fused is None:
+        fused = ir.find(tr.FusedSuperstepOp).fused
+    pplan = pre.layouts_for(g).pull_plan(cfg.pull_block_slots)
+    sweep, _ = tr._emit_dense_pull_reduce(ir, fused, pplan, cfg, False)
+    layouts = {"pull": tr._pull_device_arrays(pplan),
+               "deg": jnp.asarray(np.asarray(g.out_degrees), jnp.int32)}
+    return jax.jit(lambda values, active: sweep(layouts, values, active)[0])
+
+
+def _full_fns(layout, reduce, form):
+    key = (layout, reduce, form)
+    if key not in _FULL_FNS:
+        gather, dtype = FULL_CASES[reduce, form]
+        g = _full_graph(layout)
+        fe = G.forward_ell(g, width=8)
+        deg = jnp.asarray(np.asarray(g.out_degrees), jnp.int32)
+        chunk = FULL_CHUNKS.get(layout, 16)
+        ident = _identity(reduce, jnp.dtype(dtype))
+
+        def full(values, active):
+            return pk.full_push_reduce(
+                fe.row_src, fe.dst.T, fe.weights.T, values, deg, active,
+                num_rows=fe.num_rows, chunk=chunk, form=form,
+                gather_fn=lambda v, w, d: gather_msg(gather, v, w, d),
+                reduce=reduce, identity=ident, num_vertices=g.num_vertices,
+                dtype=jnp.dtype(dtype), gather_module=gather)
+
+        src, dst, wgt = (jnp.asarray(a) for a in G.to_coo(g))
+        _FULL_FNS[key] = (
+            fe, dtype, jax.jit(full),
+            _dense_pull_sweep(g, gather, reduce, dtype),
+            lambda values, active: push_scatter_reduce_ref(
+                src, dst, wgt, values, deg, active, gather=gather,
+                reduce=reduce)[0])
+    return _FULL_FNS[key]
+
+
+def _frontier(kind):
+    if kind == "empty":
+        return np.zeros(FULL_V, bool)
+    if kind == "one":
+        one = np.zeros(FULL_V, bool)
+        one[int(np.argmax(FULL_DEG))] = True       # a hub: 3 rows
+        return one
+    if kind == "half":
+        return np.random.default_rng(43).random(FULL_V) < 0.5
+    return np.ones(FULL_V, bool)
+
+
+@pytest.mark.parametrize("frontier", ["empty", "one", "half", "all"])
+@pytest.mark.parametrize("layout",
+                         [*FULL_CHUNKS, "edgeless"])
+@pytest.mark.parametrize("form", pk.MESSAGE_FORMS)
+@pytest.mark.parametrize("reduce", ["min", "max", "add"])
+def test_full_push_matches_dense_sweep_and_ref(reduce, form, layout,
+                                               frontier):
+    """The full push over every forward-ELL row equals the dense pull
+    sweep and the push-scatter oracle bit for bit (min, max, integer
+    add), whether R is a whole number of chunks, a ragged one (the
+    clamped last chunk must not count rows twice under add), below one
+    chunk, or 0."""
+    fe, dtype, full, sweep, ref = _full_fns(layout, reduce, form)
+    if layout == "edgeless":
+        assert fe.num_rows == 0
+    else:
+        assert fe.num_rows == 75
+        chunk = FULL_CHUNKS[layout]
+        assert {"multiple": fe.num_rows % chunk == 0,
+                "ragged": 0 < fe.num_rows % chunk < fe.num_rows,
+                "one_chunk": fe.num_rows < chunk}[layout]
+    rng = np.random.default_rng(44)
+    if dtype == np.int32:
+        values = jnp.asarray(rng.integers(-20, 50, FULL_V), jnp.int32)
+    else:
+        values = jnp.asarray(rng.uniform(-5, 5, FULL_V), jnp.float32)
+    active = jnp.asarray(_frontier(frontier))
+    got = np.asarray(full(values, active))
+    np.testing.assert_array_equal(got, np.asarray(sweep(values, active)))
+    np.testing.assert_array_equal(got, np.asarray(ref(values, active)))
+    if frontier == "empty" or layout == "edgeless":
+        assert (got == np.asarray(_identity(reduce, jnp.dtype(dtype)),
+                                  dtype)).all()
+
+
+# ---------------------------------------------------------------------------
 # 4. preprocessing cache + translate-time breakdown + staging cache
 # ---------------------------------------------------------------------------
 
@@ -326,23 +470,82 @@ def test_translate_unhashable_program_skips_cache():
 # ---------------------------------------------------------------------------
 
 
-def test_push_run_uses_fallback_beyond_tiers():
-    """A frontier wider than the largest tier must take the dense fallback
-    (recorded in run_stats) and stay bit-exact."""
-    src, dst = G.rmat_edges(400, 4000, seed=21)
-    g = G.from_edge_list(src, dst, num_vertices=400)
-    c = translate(dsl.wcc_program(), g,
+def _levels_ref(g, root):
+    """BFS levels by a host frontier loop (-1 where unreached)."""
+    off, dst = np.asarray(g.edge_offsets), np.asarray(g.edges_dst)
+    level = np.full(g.num_vertices, -1)
+    level[root], frontier, d = 0, [root], 0
+    while frontier:
+        d += 1
+        nxt = np.unique(np.concatenate(
+            [dst[off[v]:off[v + 1]] for v in frontier]))
+        nxt = nxt[level[nxt] < 0]
+        level[nxt] = d
+        frontier = list(nxt)
+    return level
+
+
+def _dist_ref(g, root):
+    """Dijkstra in float32 arithmetic: each path's left-to-right float32
+    sum, minimised — the fixpoint the min-plus supersteps reach."""
+    import heapq
+    off, dst = np.asarray(g.edge_offsets), np.asarray(g.edges_dst)
+    w = np.asarray(g.edge_weights, np.float32)
+    dist = np.full(g.num_vertices, np.inf, np.float32)
+    dist[root] = 0
+    heap = [(np.float32(0), root)]
+    while heap:
+        dv, v = heapq.heappop(heap)
+        if dv > dist[v]:
+            continue
+        for e in range(off[v], off[v + 1]):
+            nd = dv + w[e]
+            if nd < dist[dst[e]]:
+                dist[dst[e]] = nd
+                heapq.heappush(heap, (nd, int(dst[e])))
+    return dist
+
+
+@pytest.fixture(scope="module")
+def wide_graph():
+    """A symmetric weighted R-MAT graph with ~4.5k forward-ELL rows, so
+    a BFS level or an all-active start exceeds the large tier (R/16)."""
+    src, dst = G.rmat_edges(4096, 16384, seed=21)
+    w = np.random.default_rng(21).uniform(0.5, 2, len(src)).astype(np.float32)
+    return G.from_edge_list(np.concatenate([src, dst]),
+                            np.concatenate([dst, src]), num_vertices=4096,
+                            weights=np.concatenate([w, w]))
+
+
+@pytest.mark.parametrize("name", ["wcc", "bfs", "sssp"])
+def test_push_run_uses_fallback_beyond_tiers(name, wide_graph):
+    """A frontier wider than the largest tier must take the full-push
+    fallback (recorded in run_stats) and stay bit-exact: against the
+    pull-pinned run, and for bfs / sssp against host references."""
+    g = wide_graph
+    assert G.forward_ell(g, width=8).num_rows >= 4096
+    program = {"wcc": dsl.wcc_program, "sssp": dsl.sssp_program,
+               "bfs": lambda: dsl.bfs_program(alg.INT_MAX)}[name]()
+    root = None if name == "wcc" else int(np.argmax(g.out_degrees))
+    c = translate(program, g,
                   ScheduleConfig(direction=DirectionPolicy(mode="push")))
     assert c.report.push_layout == "fwd_ell"
-    labels, _ = c.run()                            # all-active start
+    got, _ = c.run(roots=root)                     # wcc: all-active start
     stats = c.last_run_stats
     assert stats["push_fallback_supersteps"] >= 1
     assert stats["push_compacted_supersteps"] \
         + stats["push_fallback_supersteps"] == stats["push_supersteps"]
-    c_pull = translate(dsl.wcc_program(), g,
+    c_pull = translate(program, g,
                        ScheduleConfig(direction=DirectionPolicy(mode="pull")))
-    want, _ = c_pull.run()
-    np.testing.assert_array_equal(np.asarray(labels), np.asarray(want))
+    want, _ = c_pull.run(roots=root)
+    got = np.asarray(got)
+    np.testing.assert_array_equal(got, np.asarray(want))
+    if name == "bfs":
+        level = _levels_ref(g, root)
+        np.testing.assert_array_equal(
+            got, np.where(level < 0, alg.INT_MAX, level))
+    elif name == "sssp":
+        np.testing.assert_array_equal(got, _dist_ref(g, root))
 
 
 def test_sparse_backend_keeps_coo_chunks_layout():

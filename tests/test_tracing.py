@@ -18,8 +18,8 @@ LOOP_STAGES = (
     "repro.loop.direction", "repro.loop.stats", "repro.pull", "repro.push",
     *(f"repro.push.tier{i}{part}" for i in (0, 1)
       for part in ("", ".compact", ".gather", ".reduce", ".apply")),
-    "repro.push.fallback", "repro.push.fallback.sweep",
-    "repro.push.fallback.apply")
+    "repro.push.fallback", "repro.push.fallback.gather",
+    "repro.push.fallback.reduce", "repro.push.fallback.apply")
 PROGRAMS = {"bfs": lambda: dsl.bfs_program(2**30), "sssp": dsl.sssp_program}
 AUTO = ScheduleConfig(direction=DirectionPolicy(mode="auto"))
 
